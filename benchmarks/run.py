@@ -15,9 +15,9 @@
   bench_kernels             kernel-level microbenchmarks
 
 `--smoke` runs the fast subset (kernels + a reduced vision-serving pass +
-the replica-scaling sweep + the streaming pass in an isolated
-single-device subprocess) and asserts the JSON reports still parse — the
-CI gate. A full (or smoke) run aggregates the per-benchmark results into a
+the replica-scaling sweep + the streaming pass, all in this one process —
+a child process could not reach an accelerator the parent holds) and
+asserts the JSON reports still parse — the CI gate. A full (or smoke) run aggregates the per-benchmark results into a
 perf-trajectory report at the repo root, BENCH_PR10.json: throughput /
 latency / analytic bytes-moved, the calibrated energy model's J/image /
 watts / FPS-per-Watt view of serving and streaming (docs/energy.md),
@@ -50,8 +50,9 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
+
+from repro.launch.compile_cache import use_compile_cache
 
 BENCH_REPORT = "BENCH_PR10.json"
 PRECISION_PARETO = "experiments/precision/mobilenet_v2_cpu_pareto.json"
@@ -73,45 +74,19 @@ def _load_baseline(path: str):
         return None
 
 
-def _run_streaming_isolated(out: str, batched_out: str,
-                            n_sessions: int = 8) -> tuple:
-    """Run bench_streaming in its own single-device subprocess.
+def _run_streaming(out: str, batched_out: str, n_sessions: int = 8) -> tuple:
+    """bench_streaming in this process: the single-stream measurement,
+    then the batched fleet sweep (`run_batched`). Both gated ratios
+    (streaming vs full-window, drain() vs serial stepping) are measured
+    in one process on one host, so they stay same-machine ratios. In
+    process, not in a child: a process that has touched JAX holds the
+    accelerator, and a child that needs it would fail or hang. Under a
+    forced multi-device host (the scaling sweep's XLA_FLAGS) the steps
+    share the split thread pool. Returns (streaming, streaming_batched)."""
+    from benchmarks import bench_streaming
 
-    The streaming step is a single-session latency path: its deployment
-    configuration is one device, and its ~3ms steps are sensitive both to
-    the virtual-device thread-pool split the scaling sweep forces
-    (``--xla_force_host_platform_device_count``) and to allocator/cache
-    state left behind by the serving benches earlier in this process. A
-    fresh subprocess with the device-count flag stripped measures the
-    configuration streaming actually serves in; the full-window reference
-    runs in the SAME subprocess, so the gated speedup remains a
-    same-process ratio. The batched fleet sweep (`run_batched`) rides in
-    the same subprocess for the same reason: its gated
-    `speedup_vs_serial_step` is a serial-vs-drain() ratio measured on one
-    host in one process. Returns (streaming, streaming_batched) dicts."""
-    env = dict(os.environ)
-    flags = [t for t in env.get("XLA_FLAGS", "").split()
-             if not t.startswith("--xla_force_host_platform_device_count")]
-    if flags:
-        env["XLA_FLAGS"] = " ".join(flags)
-    else:
-        env.pop("XLA_FLAGS", None)
-    res = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_streaming",
-         "--sessions", str(n_sessions), "--out", out,
-         "--batched", "--batched-out", batched_out],
-        env=env, capture_output=True, text=True)
-    sys.stderr.write(res.stderr)
-    for line in res.stdout.splitlines():
-        if line and line != "name,us_per_call,derived":
-            print(line)
-    if res.returncode:
-        raise RuntimeError(
-            f"bench_streaming subprocess exited {res.returncode}")
-    with open(out) as f:
-        streaming = json.load(f)
-    with open(batched_out) as f:
-        batched = json.load(f)
+    streaming = bench_streaming.run(n_sessions=n_sessions, out=out)
+    batched = bench_streaming.run_batched(out=batched_out)
     return streaming, batched
 
 
@@ -486,6 +461,7 @@ def main(argv=None) -> None:
     ap.add_argument("--regression-threshold", type=float, default=0.25,
                     help="relative FPS drop that fails the gate")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     # snapshot gate baselines BEFORE running: this run overwrites
     # BENCH_PR4.json, which is itself a valid (committed) baseline
@@ -542,14 +518,12 @@ def main(argv=None) -> None:
             # (the speedup / frames_ratio gates compare like against
             # like; fewer timed windows makes the ~3ms streaming steps
             # noise-dominated and under-reports the speedup). Only the
-            # session-table sizing is trimmed — it is untimed. Runs in an
-            # isolated single-device subprocess (see
-            # _run_streaming_isolated). The batched fleet sweep keeps its
+            # session-table sizing is trimmed — it is untimed. The batched
+            # fleet sweep keeps its
             # full default config too — its gated speedup_vs_serial_step
             # compares like against like with the committed baseline.
             (bench_streaming, "streaming",
-             lambda: _run_streaming_isolated(streaming_out, batched_out,
-                                             n_sessions=2)),
+             lambda: _run_streaming(streaming_out, batched_out, n_sessions=2)),
         ]
     else:
         plan = [
@@ -564,7 +538,7 @@ def main(argv=None) -> None:
             (bench_vision_serving, "scaling",
              lambda: bench_vision_serving.run_scaling(out=scaling_out)),
             (bench_streaming, "streaming",
-             lambda: _run_streaming_isolated(streaming_out, batched_out)),
+             lambda: _run_streaming(streaming_out, batched_out)),
         ]
 
     for mod, slot, fn in plan:

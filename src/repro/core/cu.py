@@ -31,6 +31,7 @@ import dataclasses
 from functools import partial
 from typing import Dict, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,6 +51,16 @@ from repro.core.integer_ops import (
     residual_fixed_consts,
 )
 from repro.core.qnet import QNet, QOp
+
+
+def _rounded(*products):
+    """Materialize f32 products before they are added. Eagerly every op
+    rounds on its own; under jit a backend may contract `x * y + z` into
+    one fused multiply-add (XLA:CPU does), which rounds once and can flip
+    a later round() — the jitted stages would drift off the eager
+    reference by 1 LSB. The barrier keeps jit's rounding the eager one."""
+    out = jax.lax.optimization_barrier(products)
+    return out[0] if len(out) == 1 else out
 
 
 def quantize_input(x: jnp.ndarray, scale: float, zp: float, bits: int = 8):
@@ -354,11 +365,12 @@ def _run_qop(x_q: jnp.ndarray, qop, fixed_point: bool,
     if op.act == G.HSIGMOID:
         # gate: y = relu6(x + 3)/6 quantized to [0, qmax] with S=1/qmax.
         # dequant the accumulator (S_x*S_w), apply hsigmoid, requantize.
-        y_fp = (
-            acc.astype(jnp.float32)
-            + qop.in_zp * jnp.asarray(qop.wsum, jnp.float32)
-        ) * (qop.in_scale * jnp.asarray(qop.w_scale, jnp.float32))
-        y_fp = y_fp + jnp.asarray(qop.bias_q, jnp.float32) * qop.out_scale
+        zterm = _rounded(qop.in_zp * jnp.asarray(qop.wsum, jnp.float32))
+        y_fp = (acc.astype(jnp.float32) + zterm) * (
+            qop.in_scale * jnp.asarray(qop.w_scale, jnp.float32))
+        y_fp, b_fp = _rounded(
+            y_fp, jnp.asarray(qop.bias_q, jnp.float32) * qop.out_scale)
+        y_fp = y_fp + b_fp
         # requantize with ONE constant multiply: chaining /6.0 with
         # /out_scale lets XLA reassociate the two divisions under jit
         # (reciprocal-multiply rewrites), flipping round() on boundary
@@ -409,8 +421,8 @@ def _residual_add(
     """
     if fixed_consts is not None:
         return int_residual_add(a_q, b_q, fixed_consts, qmax)
-    a = (a_q.astype(jnp.float32) + a_z) * (a_s / y_s)
-    b = (b_q.astype(jnp.float32) + b_z) * (b_s / y_s)
+    a, b = _rounded((a_q.astype(jnp.float32) + a_z) * (a_s / y_s),
+                    (b_q.astype(jnp.float32) + b_z) * (b_s / y_s))
     return jnp.clip(jnp.round(a + b) - round(y_z), 0, qmax).astype(jnp.int32)
 
 

@@ -22,6 +22,31 @@ def requant_clip(acc, mult, zcorr, bias_q, qmax: int, clip: bool = True):
     return y
 
 
+LANES = 128  # TPU vreg lane width: the last block dim's tiling unit
+
+
+def lane_block(n: int, cap: int) -> int:
+    """Block size for a lane (last) dimension of `n`: the largest multiple
+    of LANES that divides `n` and is <= `cap`, else the whole dimension —
+    the two block shapes the TPU's (8, 128) tiling accepts."""
+    for b in range(cap // LANES * LANES, 0, -LANES):
+        if n % b == 0:
+            return b
+    return n
+
+
+def largest_divisor(n: int, cap: int) -> int:
+    """Largest d <= cap with n % d == 0 (d >= 1)."""
+    for d in range(min(cap, n), 0, -1):
+        if n % d == 0:
+            return d
+    return 1
+
+
+def round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def same_pad_amount(size: int, kernel: int, stride: int):
     """SAME padding (lo, hi) for one spatial dim."""
     out = -(-size // stride)
@@ -30,4 +55,5 @@ def same_pad_amount(size: int, kernel: int, stride: int):
     return lo, total - lo, out
 
 
-__all__ = ["requant_clip", "same_pad_amount"]
+__all__ = ["LANES", "lane_block", "largest_divisor", "requant_clip", "round_up",
+           "same_pad_amount"]

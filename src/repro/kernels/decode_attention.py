@@ -80,7 +80,7 @@ def decode_attention(
     v_scale: jnp.ndarray = None,
     *,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     b, kv, rep, dh = q.shape
     s = k_cache.shape[1]

@@ -10,20 +10,21 @@ fully unrolled as shifted vector multiplies over the (rows, cols) plane — the
 VPU analogue of K*K*N parallel MACs; there is nothing for the MXU to do (that
 is the paper's point: systolic arrays waste FMAs on depthwise).
 
-Grid: (batch, channel_tiles, row_tiles), row tiles innermost — the input
-block's index map does not depend on the row-tile coordinate, so the
-[H, W, block_c] slab is fetched HBM->VMEM once per (batch, channel tile) and
-stays resident while every row strip of it is processed. HBM holds only the
-RAW activations — SAME padding happens in-kernel (VMEM-local zero pad + halo
-slice per row strip), so no jnp.pad-ed copy of the feature map is ever
-materialized in HBM; this mirrors the line buffer, which also pads at the
-window, not in DDR. Each grid step slices its strip (with K-1 halo rows) out
-of the slab, runs the unrolled K x K accumulation for `block_h` output rows,
-applies the per-channel requant epilogue and writes
-[block_h, W_out, block_c].
+Grid: (batch, channel_tiles, row_tiles). The wrapper applies the SAME zero
+padding; each grid step then DMAs one input strip — its `block_h` output
+rows' worth of input plus the K - stride halo rows — into VMEM through an
+element-offset block (strips overlap by the halo, which a plain blocked
+spec cannot express). That strip is the line buffer: VMEM holds
+O(block_h * W * 128), never the whole plane, so a 112 x 112 layer fits.
+Each of the K x K taps is a (strided, for stride 2) window read straight
+from the strip ref; the unrolled multiply-accumulate runs on the VPU, the
+per-channel requant epilogue follows, and the step writes
+[block_h, W_out, 128].
 
-Depthwise inputs are ReLU6-fused quantized (zero-point 0), so the in-kernel
-zero padding is exact.
+The wrapper also zero-pads C up to a multiple of 128 and every block is
+128 lanes wide: Mosaic takes element-offset blocks only on tile-aligned
+lanes, and strided window reads only from a 128-lane ref. Depthwise inputs
+are ReLU6-fused quantized (zero-point 0), so the zero padding is exact.
 
 CU mapping (see README 'Performance'): this kernel is the DW op's compiled
 path on TPU, and the Body CU's dw stage when the fused-IRB kernel does not
@@ -38,39 +39,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import requant_clip
+from repro.kernels.common import LANES, requant_clip, round_up, same_pad_amount
 
 
 def _dw_kernel(x_ref, w_ref, mult_ref, zcorr_ref, bias_ref, o_ref,
-               *, kernel: int, stride: int, th: int, w_out: int,
-               pad_top: int, pad_left: int, hp: int, wp: int, qmax: int,
+               *, kernel: int, stride: int, th: int, w_out: int, qmax: int,
                clip: bool):
-    x = x_ref[0].astype(jnp.int32)  # [H, W, bc] — raw, unpadded
-    bc = x.shape[-1]
-    # VMEM-local SAME padding (zp == 0 for ReLU6-fused dw inputs)
-    xp = jnp.pad(
-        x,
-        ((pad_top, hp - pad_top - x.shape[0]),
-         (pad_left, wp - pad_left - x.shape[1]),
-         (0, 0)),
-    )
-    # this strip's rows (including the K-1 halo); grid dim 2 is the row tile
-    nrows = (th - 1) * stride + kernel
-    row0 = pl.program_id(2) * th * stride
-    strip = jax.lax.dynamic_slice(xp, (row0, 0, 0), (nrows, wp, bc))
-    w = w_ref[...].astype(jnp.int32)  # [K, K, bc]
+    # x_ref: [1, (th-1)*stride + K, Wp, bc] — this strip of the padded input
+    bc = o_ref.shape[-1]
     acc = jnp.zeros((th, w_out, bc), jnp.int32)
     # K x K unrolled shifted multiply-accumulate == the sliding window
     for ki in range(kernel):
         for kj in range(kernel):
-            patch = jax.lax.slice(
-                strip,
-                (ki, kj, 0),
-                (ki + (th - 1) * stride + 1,
-                 kj + (w_out - 1) * stride + 1, bc),
-                (stride, stride, 1),
-            )
-            acc = acc + patch * w[ki, kj][None, None, :]
+            patch = x_ref[0, pl.ds(ki, th, stride=stride),
+                          pl.ds(kj, w_out, stride=stride), :]
+            t = ki * kernel + kj
+            acc = acc + patch * w_ref[t:t + 1, :]
     y = requant_clip(acc, mult_ref[...], zcorr_ref[...], bias_ref[...], qmax,
                      clip)
     o_ref[0] = y.astype(o_ref.dtype)
@@ -78,7 +62,7 @@ def _dw_kernel(x_ref, w_ref, mult_ref, zcorr_ref, bias_ref, o_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("kernel", "stride", "qmax", "clip", "block_c", "block_h",
+    static_argnames=("kernel", "stride", "qmax", "clip", "block_h",
                      "interpret"),
 )
 def depthwise_conv_q(
@@ -92,64 +76,52 @@ def depthwise_conv_q(
     stride: int = 1,
     qmax: int = 15,
     clip: bool = True,
-    block_c: int = 128,
     block_h: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Pallas depthwise conv, SAME padding, grid (B, C_tiles, row_tiles).
 
     `block_h` output rows per grid step (shrunk to the largest divisor of
-    H_out); padding is applied in-kernel and the input slab is re-used
-    across the innermost row-tile steps, so HBM traffic is the raw input +
-    output + weights. Returns int32 in [0, qmax].
+    H_out); channels run in 128-lane blocks. Returns int32 in [0, qmax].
     """
     b, h, w, c = x_q.shape
-    from repro.kernels.common import same_pad_amount
-
     ph_lo, ph_hi, h_out = same_pad_amount(h, kernel, stride)
     pw_lo, pw_hi, w_out = same_pad_amount(w, kernel, stride)
-    bc = min(block_c, c)
-    if c % bc:
-        raise ValueError(f"channels {c} must be divisible by block_c {bc}")
+    cp = round_up(c, LANES)
     th = min(block_h, h_out)
     while h_out % th:
         th -= 1
-    # in-kernel pad must cover the last strip's halo rows
-    nrows = (th - 1) * stride + kernel
-    max_row = (h_out // th - 1) * th * stride + nrows
-    hp = max(ph_lo + h + ph_hi, max_row)
-    wp = pw_lo + w + pw_hi
+    nrows = (th - 1) * stride + kernel  # strip rows incl. the halo
+    xp = jnp.pad(x_q.astype(jnp.int32),
+                 ((0, 0), (ph_lo, ph_hi), (pw_lo, pw_hi), (0, cp - c)))
+    wp = xp.shape[2]
 
-    # row tiles innermost: the x/w/const block indices ignore the row-tile
-    # coordinate, so those blocks stay VMEM-resident across consecutive steps
-    grid = (b, c // bc, h_out // th)
+    def lanes(v):  # [..., C] -> [..., Cp], zero-padded channels
+        return jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, cp - c)])
+
+    el, bc = pl.Element, LANES
+    grid = (b, cp // bc, h_out // th)
     out = pl.pallas_call(
-        functools.partial(
-            _dw_kernel,
-            kernel=kernel,
-            stride=stride,
-            th=th,
-            w_out=w_out,
-            pad_top=ph_lo,
-            pad_left=pw_lo,
-            hp=hp,
-            wp=wp,
-            qmax=qmax,
-            clip=clip,
-        ),
+        functools.partial(_dw_kernel, kernel=kernel, stride=stride, th=th,
+                          w_out=w_out, qmax=qmax, clip=clip),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, h, w, bc), lambda i, k, j: (i, 0, 0, k)),
-            pl.BlockSpec((kernel, kernel, bc), lambda i, k, j: (0, 0, k)),
-            pl.BlockSpec((bc,), lambda i, k, j: (k,)),
-            pl.BlockSpec((bc,), lambda i, k, j: (k,)),
-            pl.BlockSpec((bc,), lambda i, k, j: (k,)),
+            # element offsets: strip j starts at padded row j*th*stride and
+            # overlaps the next strip by the K - stride halo rows
+            pl.BlockSpec((el(1), el(nrows), el(wp), el(bc)),
+                         lambda i, k, j: (i, j * th * stride, 0, k * bc)),
+            pl.BlockSpec((kernel * kernel, bc), lambda i, k, j: (0, k)),
+            pl.BlockSpec((1, bc), lambda i, k, j: (0, k)),
+            pl.BlockSpec((1, bc), lambda i, k, j: (0, k)),
+            pl.BlockSpec((1, bc), lambda i, k, j: (0, k)),
         ],
         out_specs=pl.BlockSpec((1, th, w_out, bc), lambda i, k, j: (i, j, 0, k)),
-        out_shape=jax.ShapeDtypeStruct((b, h_out, w_out, c), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, h_out, w_out, cp), jnp.int32),
         interpret=interpret,
-    )(x_q, w_q, mult, zcorr, bias_q)
-    return out
+    )(xp, lanes(w_q.reshape(kernel * kernel, c).astype(jnp.int32)),
+      lanes(mult.reshape(1, c)), lanes(zcorr.reshape(1, c)),
+      lanes(bias_q.reshape(1, c)))
+    return out[..., :c] if cp != c else out
 
 
 __all__ = ["depthwise_conv_q"]

@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 These adapt QNet metadata (per-channel scales, zero-point corrections) into
-the raw kernel signatures, pick interpret mode automatically (CPU container
--> interpret=True; real TPU -> compiled), and expose a float `quantized_linear`
+the raw kernel signatures, pick interpret mode from the platform (CPU ->
+interpret=True; TPU -> compiled; this is the one place that decides — the
+raw kernels default to compiled), and expose a float `quantized_linear`
 for the LM architectures (weight-only quantization, the paper's Sec. 3.2 math).
 
 Every wrapper accepts either a host `QOp` or a device-resident
@@ -32,6 +33,7 @@ from repro.core import cu as _cu
 from repro.core import graph as G
 from repro.core.quant import QuantConfig
 from repro.kernels import depthwise_conv as _dw
+from repro.kernels.common import largest_divisor
 from repro.kernels import fused_irb as _irb
 from repro.kernels import pointwise_conv as _pw
 from repro.kernels import quant_matmul as _qmm
@@ -70,13 +72,6 @@ def _mat_weight(qop) -> jnp.ndarray:
     return w[0, 0] if w.ndim == 4 else w
 
 
-def _pick_block_c(c: int) -> int:
-    for cand in (128, 64, 32, 16, 8):
-        if c % cand == 0 and c >= cand:
-            return cand
-    return c
-
-
 def run_dw_qop(x_q: jnp.ndarray, qop, interpret: Optional[bool] = None,
                block_h: int = 8):
     """Depthwise QNet op via the row-tiled Pallas kernel."""
@@ -85,8 +80,7 @@ def run_dw_qop(x_q: jnp.ndarray, qop, interpret: Optional[bool] = None,
     return _dw.depthwise_conv_q(
         x_q, _dw_weight(qop), mult, zcorr, bias,
         kernel=qop.spec.kernel, stride=qop.spec.stride, qmax=qop.qmax,
-        clip=qop.clip, block_c=_pick_block_c(x_q.shape[-1]),
-        block_h=block_h, interpret=interp,
+        clip=qop.clip, block_h=block_h, interpret=interp,
     )
 
 
@@ -152,27 +146,24 @@ def run_irb_block(
     interp = (not on_tpu()) if interpret is None else interpret
     assert len(block.ops) == 3 and block.se is None
     q1, q2, q3 = (qnet.ops[op.name] for op in block.ops)
-    m1, c1, b1 = _epilogue_consts(q1)
+    m1, _, b1 = _epilogue_consts(q1)
     m2, c2, b2 = _epilogue_consts(q2)
-    m3, c3, b3 = _epilogue_consts(q3)
+    m3, _, b3 = _epilogue_consts(q3)
     res_consts = None
     out_s, out_z = q3.out_scale, q3.out_zp
     if block.residual:
         y_s, y_z = qnet.res_q[block.name]
-        res_consts = (
-            in_s / y_s,
-            in_s / y_s * in_z - round(y_z),
-            q3.out_scale / y_s,
-            q3.out_scale / y_s * q3.out_zp,
-        )
+        # the float arithmetic of cu._residual_add, constant for constant
+        res_consts = (in_z, in_s / y_s, q3.out_zp, q3.out_scale / y_s,
+                      round(y_z))
         out_s, out_z = y_s, y_z
     y = _irb.fused_irb_q(
         x_q,
         _mat_weight(q1),
-        m1, c1, b1,
+        m1, _pw_zpc(q1), b1,
         _dw_weight(q2), m2, c2, b2,
         _mat_weight(q3),
-        m3, c3, b3,
+        m3, _pw_zpc(q3), b3,
         kernel=q2.spec.kernel,
         stride=q2.spec.stride,
         qmax=q3.qmax,
@@ -279,7 +270,7 @@ def quantized_linear(
     n = w_q.shape[1] * (2 if bits == 4 else 1)
     # largest divisor of N at most 128 (one giant N block would blow VMEM
     # for non-multiple-of-128 N; any divisor tiles exactly)
-    bn = _pw._largest_divisor(n, 128)
+    bn = largest_divisor(n, 128)
     group = k // w_scale.shape[0]
     bk = min(512, group) if group < 512 or group % 512 else 512
     # bk must divide K and align with the scale-group size; halving can

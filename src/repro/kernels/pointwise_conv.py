@@ -18,6 +18,12 @@ form. The same kernel serves:
 
 i.e. every op the CU planner maps to a matmul engine.
 
+MXU operands: activations are unsigned (x in [0, 255]) and the MXU takes
+signed int8, so the kernel multiplies (x - 128) and the wrapper folds the
+shift back into the integer zero-point correction (128 * wsum). Blocks obey
+the TPU tiling rule: each of bn/bk is a multiple of 128 that divides its
+dimension, else the whole dimension; bm is a multiple of 8 (M is padded).
+
 Epilogue exactness: the kernel receives the INTEGER zero-point correction
 `zpc = int32(z_x) * wsum` (per output channel) and computes
 
@@ -35,15 +41,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import requant_clip
+from repro.kernels.common import lane_block, requant_clip, round_up
 
-
-def _largest_divisor(n: int, cap: int) -> int:
-    """Largest d <= cap with n % d == 0 (d >= 1)."""
-    for d in range(min(cap, n), 0, -1):
-        if n % d == 0:
-            return d
-    return 1
+# unsigned activations -> signed int8 MXU operands (see module docstring)
+_X_SHIFT = 128
 
 
 def _pw_kernel(x_ref, w_ref, mult_ref, zpc_ref, bias_ref, o_ref,
@@ -54,13 +55,12 @@ def _pw_kernel(x_ref, w_ref, mult_ref, zpc_ref, bias_ref, o_ref,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.int32)  # [bm, bk]
-    w = w_ref[...].astype(jnp.int32)  # [bk, bn]
-    o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.int32)
+    x = (x_ref[...] - _X_SHIFT).astype(jnp.int8)  # [bm, bk]
+    o_ref[...] += jnp.dot(x, w_ref[...], preferred_element_type=jnp.int32)
 
     @pl.when(k == nsteps - 1)
     def _epilogue():
-        acc = o_ref[...] + zpc_ref[...].astype(jnp.int32)[None, :]
+        acc = o_ref[...] + zpc_ref[...]
         o_ref[...] = requant_clip(
             acc, mult_ref[...], jnp.float32(0.0), bias_ref[...], qmax, clip)
 
@@ -71,8 +71,8 @@ def _pw_kernel(x_ref, w_ref, mult_ref, zpc_ref, bias_ref, o_ref,
                      "interpret"),
 )
 def pointwise_conv_q(
-    x_q: jnp.ndarray,  # [..., C_in] int quantized activations
-    w_q: jnp.ndarray,  # [C_in, C_out] int8 symmetric per-out-channel weights
+    x_q: jnp.ndarray,  # [..., C_in] int activations in [0, 255]
+    w_q: jnp.ndarray,  # [C_in, C_out] int8-range symmetric per-out-channel
     mult: jnp.ndarray,  # [C_out] f32 requant multiplier S_x*S_w/S_y
     zpc: jnp.ndarray,  # [C_out] i32 integer zero-point correction z_x*wsum
     bias_q: jnp.ndarray,  # [C_out] i32 bias in output units (z_y folded)
@@ -82,29 +82,35 @@ def pointwise_conv_q(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Pallas pointwise conv / dense matmul with the fused integer epilogue.
 
-    Flattens leading dims to M, pads M up to a block multiple (pad rows are
-    computed then discarded), and picks N/K blocks as the largest divisors
-    within the requested block sizes, so any channel count compiles.
+    Flattens leading dims to M and pads M up to a block multiple (pad rows
+    are computed then discarded). `block_n`/`block_k` are caps: each block
+    is the largest multiple of 128 within the cap that divides its
+    dimension, else the whole dimension, so any channel count compiles.
     Returns int32 in [0, qmax] with the input's leading shape + [C_out].
     """
     lead = x_q.shape[:-1]
     k_dim = x_q.shape[-1]
     n_dim = w_q.shape[-1]
-    x2 = x_q.reshape(-1, k_dim)
+    x2 = x_q.reshape(-1, k_dim).astype(jnp.int32)
     m = x2.shape[0]
 
-    bm = min(block_m, m)
+    bm = max(8, min(block_m, round_up(m, 8)) // 8 * 8)
     pad = (-m) % bm
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     mp = m + pad
-    bn = _largest_divisor(n_dim, block_n)
-    bk = _largest_divisor(k_dim, block_k)
+    bn = lane_block(n_dim, block_n)
+    bk = lane_block(k_dim, block_k)
+    w8 = w_q.astype(jnp.int8)
+    # sum_k (x - 128) w + 128 * wsum == sum_k x w: the shift rides in zpc
+    wsum = jnp.sum(w_q.astype(jnp.int32), axis=0)
+    zpc = zpc.astype(jnp.int32) + _X_SHIFT * wsum
 
+    row = lambda v: v.reshape(1, n_dim)  # noqa: E731 — (1, N) lane vectors
     grid = (mp // bm, n_dim // bn, k_dim // bk)
     out = pl.pallas_call(
         functools.partial(_pw_kernel, nsteps=grid[2], qmax=qmax, clip=clip),
@@ -112,14 +118,14 @@ def pointwise_conv_q(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n_dim), jnp.int32),
         interpret=interpret,
-    )(x2, w_q, mult, zpc, bias_q)
+    )(x2, w8, row(mult), row(zpc), row(bias_q))
     if pad:
         out = out[:m]
     return out.reshape(*lead, n_dim)

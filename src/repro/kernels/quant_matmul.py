@@ -53,7 +53,7 @@ def quant_matmul(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     m, k = x.shape
     n = w_q.shape[1] * (2 if bits == 4 else 1)

@@ -28,23 +28,23 @@ def depthwise_conv_q_ref(x_q, w_q, mult, zcorr, bias_q, *, kernel=3, stride=1,
 
 def fused_irb_q_ref(
     x_q,
-    w1_q, mult1, zcorr1, bias1,
+    w1_q, mult1, zpc1, bias1,
     w2_q, mult2, zcorr2, bias2,
-    w3_q, mult3, zcorr3, bias3,
+    w3_q, mult3, zpc3, bias3,
     *,
     kernel=3,
     stride=1,
     qmax=15,
     residual=False,
-    res_scale=None,  # (a_mult, a_off, b_mult, b_off, qmax) for the skip add
+    res_scale=None,  # (a_z, a_s/y_s, b_z, b_s/y_s, round(y_z)) skip add
 ):
     """Oracle for kernels.fused_irb.fused_irb_q: pw-expand -> dw -> pw-project."""
-    # stage 1: pointwise expansion (ReLU6 fused)
+    # stage 1: pointwise expansion (ReLU6 fused), integer zero-point term
     acc1 = jnp.einsum(
         "bhwc,ce->bhwe", x_q.astype(jnp.int32), w1_q.astype(jnp.int32),
         preferred_element_type=jnp.int32,
-    )
-    e = requant_clip(acc1, mult1, zcorr1, bias1, qmax, clip=True)
+    ) + zpc1
+    e = requant_clip(acc1, mult1, 0.0, bias1, qmax, clip=True)
     # stage 2: depthwise (ReLU6 fused)
     d = depthwise_conv_q_ref(
         e, w2_q, mult2, zcorr2, bias2, kernel=kernel, stride=stride, qmax=qmax,
@@ -54,13 +54,13 @@ def fused_irb_q_ref(
     acc3 = jnp.einsum(
         "bhwe,eo->bhwo", d.astype(jnp.int32), w3_q.astype(jnp.int32),
         preferred_element_type=jnp.int32,
-    )
-    y = requant_clip(acc3, mult3, zcorr3, bias3, qmax, clip=True)
+    ) + zpc3
+    y = requant_clip(acc3, mult3, 0.0, bias3, qmax, clip=True)
     if residual:
-        a_mult, a_off, b_mult, b_off = res_scale
-        a = x_q.astype(jnp.float32) * a_mult + a_off
-        bq = y.astype(jnp.float32) * b_mult + b_off
-        y = jnp.clip(jnp.round(a + bq), 0, qmax).astype(jnp.int32)
+        a_z, r_a, b_z, r_b, zy = res_scale
+        a = (x_q.astype(jnp.float32) + a_z) * r_a
+        bq = (y.astype(jnp.float32) + b_z) * r_b
+        y = jnp.clip(jnp.round(a + bq) - zy, 0, qmax).astype(jnp.int32)
     return y
 
 

@@ -13,16 +13,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types where this jax supports them.
-
-    `jax.sharding.AxisType` (and the `axis_types=` kwarg) only exist in
-    newer jax; older versions treat every axis as Auto already, so the
-    plain call is equivalent there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis of Auto type."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

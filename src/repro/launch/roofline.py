@@ -129,8 +129,6 @@ class Roofline:
 
 def from_compiled(compiled, n_devices: int) -> Roofline:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

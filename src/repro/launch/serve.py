@@ -5,11 +5,14 @@ LM serving:
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --reduced \
         --requests 8 [--quant-bits 8]
 
-Vision serving (sharded multi-replica, multi-model):
+Vision serving at the published design points (MobileNetV2 alpha 1.0 at
+224, compact EfficientNet at 128), multi-model, sharded over 4 replicas:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
     PYTHONPATH=src python -m repro.launch.serve --vision --replicas 4 \
         --models mobilenet_v2,efficientnet_compact --requests 32
+
+(on a CPU host, `XLA_FLAGS=--xla_force_host_platform_device_count=4`
+provides the 4 devices; `--alpha 0.35 --hw 48` is a quick CPU-sized point).
 
 Serve-time weight quantization (--quant-bits) applies the paper's range-based
 symmetric per-channel scheme to every linear operator — the LM analogue of
@@ -27,24 +30,32 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.lm import model as M
 from repro.serve.engine import Engine, Request
 
 VISION_ARCHS = ("mobilenet_v2", "efficientnet_compact")
 
 
-def _vision_qnet(arch: str, hw: int, seed: int = 0):
-    from repro.models import efficientnet as effn, layers, mobilenet_v2 as mnv2
+def _vision_qnet(arch: str, hw: Optional[int] = None, alpha: float = 1.0,
+                 seed: int = 0):
+    """Calibrated QNet of a published design point: `configs/<arch>.py`
+    at its published input resolution unless `hw` overrides it; `alpha`
+    is MobileNetV2's width multiplier."""
+    from repro.configs import efficientnet_compact, mobilenet_v2
+    from repro.models import layers
 
+    kw = {} if hw is None else {"input_hw": hw}
     if arch == "mobilenet_v2":
-        net = mnv2.build(alpha=0.35, input_hw=hw, num_classes=1000)
+        net = mobilenet_v2.get_config(alpha=alpha, **kw)
     elif arch == "efficientnet_compact":
-        net = effn.build_compact(input_hw=hw, num_classes=1000)
+        net = efficientnet_compact.get_config(**kw)
     else:
         raise ValueError(f"unknown vision arch {arch!r} (pick from {VISION_ARCHS})")
     return layers.make_calibrated_qnet(net, seed=seed)
@@ -75,7 +86,10 @@ def _vision_tuned(args, qnets):
     return None
 
 
-def vision_main(args) -> None:
+def vision_main(args):
+    """Serve `args.requests` random images through the EDF router.
+    Returns (router, sent, results): `sent` maps each (model, rid) handle
+    to the image submitted under it, `results` is `router.run()`'s."""
     from repro.dist.sharding import data_mesh
     from repro.serve.vision import MultiModelEngine, VisionEngine
 
@@ -92,7 +106,11 @@ def vision_main(args) -> None:
     buckets = tuple(sorted(
         {b for b in (1, 2, 4) if b < args.batch} | {args.batch}))
     models = [m.strip() for m in args.models.split(",") if m.strip()]
-    qnets = {m: _vision_qnet(m, args.hw, args.seed) for m in models}
+    t0 = time.perf_counter()
+    qnets = {m: _vision_qnet(m, args.hw, args.alpha, args.seed)
+             for m in models}
+    print(f"[serve-vision] calibrated {', '.join(qnets[m].spec.name for m in models)} "
+          f"in {time.perf_counter() - t0:.2f}s")
     tuned = _vision_tuned(args, qnets)
     if tuned is not None:
         for m, q in qnets.items():
@@ -107,13 +125,19 @@ def vision_main(args) -> None:
     if args.power_budget_w:
         print(f"[serve-vision] power cap {args.power_budget_w:.1f} W "
               f"shared across {len(models)} model(s)")
+    t0 = time.perf_counter()
     router.warmup()
+    print(f"[serve-vision] warmup (every stage x bucket traced and "
+          f"compiled) {time.perf_counter() - t0:.2f}s")
     rng = np.random.default_rng(args.seed)
     now = time.perf_counter()
+    sent = {}
     for i in range(args.requests):
-        img = rng.uniform(-1, 1, (args.hw, args.hw, 3)).astype(np.float32)
+        m = models[i % len(models)]
+        img = rng.uniform(-1, 1, qnets[m].spec.input_shape()).astype(
+            np.float32)
         deadline = now + 5.0 if i % 3 == 0 else None
-        router.submit(models[i % len(models)], img, deadline_s=deadline)
+        sent[router.submit(m, img, deadline_s=deadline)] = img
     results = router.run()
     n_ok = sum(1 for r in results.values() if r.status == "ok")
     print(f"[serve-vision] {n_ok}/{len(results)} ok over "
@@ -138,6 +162,7 @@ def vision_main(args) -> None:
         print(render_report(
             summarize_trace(tracer.to_chrome()) if tracer else None,
             metrics.snapshot() if metrics else None))
+    return router, sent, results
 
 
 def main(argv=None):
@@ -149,7 +174,12 @@ def main(argv=None):
                          f"(from {', '.join(VISION_ARCHS)})")
     ap.add_argument("--replicas", type=int, default=1,
                     help="data-parallel replicas (vision; needs devices)")
-    ap.add_argument("--hw", type=int, default=48, help="vision input H=W")
+    ap.add_argument("--hw", type=int, default=None,
+                    help="vision input H=W (default: each arch's published "
+                         "resolution, 224 / 128)")
+    ap.add_argument("--alpha", type=float, default=1.0,
+                    help="MobileNetV2 width multiplier (published: 1.0, "
+                         "0.75, 0.5, 0.35)")
     ap.add_argument("--batch", type=int, default=8,
                     help="largest vision micro-batch bucket")
     ap.add_argument("--tune", action="store_true",
@@ -179,6 +209,7 @@ def main(argv=None):
     ap.add_argument("--quant-bits", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.vision:
         return vision_main(args)

@@ -35,6 +35,7 @@ from typing import Callable, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import compiler as CC
 from repro.dist.sharding import batch_sharding
@@ -92,14 +93,22 @@ class CompiledStage:
         self.on_retrace: Optional[Callable[["CompiledStage", Tuple[int, ...]],
                                            None]] = None
         jit_kwargs = dict(donate_argnums=(0,) if donate else ())
+        self._body = self._compute
         if mesh is not None:
             # data-parallel replication: micro-batch rows split along the
             # mesh 'data' axis in AND out, so an N-replica mesh runs N
             # shards of every CU invocation concurrently. Constants are
             # replicated (prepare_qnet(mesh=...)), activations stay sharded
             # across the whole executor chain — no resharding between CUs.
+            # Rows are independent, so each replica runs the stage on its
+            # own rows under shard_map: the compiler cannot partition a
+            # Pallas kernel itself (and pallas_call declares no per-axis
+            # variance, hence check_vma=False).
             ns = batch_sharding(mesh)
             jit_kwargs.update(in_shardings=ns, out_shardings=ns)
+            self._body = jax.shard_map(
+                self._compute, mesh=mesh, in_specs=P("data"),
+                out_specs=P("data"), check_vma=False)
         self._fn = jax.jit(self._trace, **jit_kwargs)
 
     def _trace(self, x: jax.Array) -> jax.Array:
@@ -118,6 +127,9 @@ class CompiledStage:
                 RuntimeWarning, stacklevel=2)
             if self.on_retrace is not None:
                 self.on_retrace(self, tuple(x.shape))
+        return self._body(x)
+
+    def _compute(self, x: jax.Array) -> jax.Array:
         spec = self.spec
         y = x
         if spec.quantizes_input:

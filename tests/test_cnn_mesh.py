@@ -49,8 +49,6 @@ fn = jax.jit(lambda x: cu.run_qnet(qn, x), in_shardings=in_sh,
 compiled = fn.lower(x_spec).compile()
 mem = compiled.memory_analysis()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-    ca = ca[0] if ca else {}
 assert mem.temp_size_in_bytes < 2e9  # tiny per-chip working set
 print("OK flops/dev=%.2e temp=%.1fMB" % (
     float(ca.get("flops", 0)), mem.temp_size_in_bytes / 1e6))

@@ -17,19 +17,21 @@ def _mk(h, w, c, K, seed=0):
     return x, wq, mult, zc, b
 
 
-@pytest.mark.parametrize("h,w,c,K,s,bc", [
+@pytest.mark.parametrize("h,w,c,K,s,bh", [
     (8, 8, 16, 3, 1, 8),
     (8, 8, 16, 3, 2, 16),
     (9, 9, 8, 3, 1, 8),       # odd spatial
     (11, 13, 8, 3, 2, 8),     # odd + rectangular + stride 2
     (12, 12, 32, 5, 1, 8),    # 5x5 kernel (EfficientNet)
     (10, 10, 24, 5, 2, 8),
-    (16, 16, 128, 3, 1, 128), # full-channel block
+    (16, 16, 128, 3, 1, 128), # one full 128-lane channel block
+    (8, 8, 200, 3, 2, 2),     # two lane blocks, the second zero-padded
+    (6, 6, 256, 3, 1, 4),     # two full lane blocks
 ])
-def test_depthwise_matches_ref(h, w, c, K, s, bc):
+def test_depthwise_matches_ref(h, w, c, K, s, bh):
     x, wq, mult, zc, b = _mk(h, w, c, K)
     y = depthwise_conv_q(x, wq, mult, zc, b, kernel=K, stride=s,
-                         block_c=bc, interpret=True)
+                         block_h=bh, interpret=True)
     yr = ref.depthwise_conv_q_ref(x, wq, mult, zc, b, kernel=K, stride=s)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))
 
@@ -38,8 +40,7 @@ def test_depthwise_matches_ref(h, w, c, K, s, bc):
 def test_depthwise_bitwidth_sweep(qmax):
     """BW in {3,4,6,8}: clip bound == fused ReLU6 at that BW."""
     x, wq, mult, zc, b = _mk(8, 8, 16, 3)
-    y = depthwise_conv_q(x, wq, mult, zc, b, qmax=qmax, block_c=8,
-                         interpret=True)
+    y = depthwise_conv_q(x, wq, mult, zc, b, qmax=qmax, interpret=True)
     yr = ref.depthwise_conv_q_ref(x, wq, mult, zc, b, qmax=qmax)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))
     assert int(y.max()) <= qmax and int(y.min()) >= 0
@@ -47,8 +48,7 @@ def test_depthwise_bitwidth_sweep(qmax):
 
 def test_depthwise_no_clip_linear_output():
     x, wq, mult, zc, b = _mk(8, 8, 8, 3)
-    y = depthwise_conv_q(x, wq, mult, zc, b, clip=False, block_c=8,
-                         interpret=True)
+    y = depthwise_conv_q(x, wq, mult, zc, b, clip=False, interpret=True)
     yr = ref.depthwise_conv_q_ref(x, wq, mult, zc, b, clip=False)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))
     assert int(y.min()) < 0  # linear path keeps negatives
@@ -70,12 +70,12 @@ except ImportError:  # container without hypothesis: deterministic fallback
     (10, 10, 16, 5, 2, 7),    # block_h not dividing H_out: shrinks to 5
 ])
 def test_depthwise_row_tiling(h, w, c, K, s, bh):
-    """Row-tiled grid (batch, row_tiles, channel_tiles): every tiling of the
+    """Row-tiled grid (batch, channel_tiles, row_tiles): every tiling of the
     output rows — including strips whose K-1 halo crosses the in-kernel
     zero padding — agrees with the oracle bit-for-bit."""
     x, wq, mult, zc, b = _mk(h, w, c, K, seed=1)
     y = depthwise_conv_q(x, wq, mult, zc, b, kernel=K, stride=s,
-                         block_c=8, block_h=bh, interpret=True)
+                         block_h=bh, interpret=True)
     yr = ref.depthwise_conv_q_ref(x, wq, mult, zc, b, kernel=K, stride=s)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))
 
@@ -92,6 +92,6 @@ def test_property_depthwise_random_geometry(h, w, c, k, s, bh, seed):
     oracle — covers stride-2 and 5x5 (EfficientNet) geometries."""
     x, wq, mult, zc, b = _mk(h, w, c, k, seed=seed)
     y = depthwise_conv_q(x, wq, mult, zc, b, kernel=k, stride=s,
-                         block_c=8, block_h=bh, interpret=True)
+                         block_h=bh, interpret=True)
     yr = ref.depthwise_conv_q_ref(x, wq, mult, zc, b, kernel=k, stride=s)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))

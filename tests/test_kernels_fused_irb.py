@@ -13,14 +13,17 @@ def _mk(c, e, co, seed=0):
     w1 = jnp.asarray(rng.integers(-7, 8, (c, e)), jnp.int32)
     w2 = jnp.asarray(rng.integers(-7, 8, (3, 3, e)), jnp.int32)
     w3 = jnp.asarray(rng.integers(-7, 8, (e, co)), jnp.int32)
-    def mk(n, z=False):
+    def mk(n, corr):
         return (
             jnp.asarray(rng.uniform(0.001, 0.01, n), jnp.float32),
-            jnp.zeros(n, jnp.float32) if z
-            else jnp.asarray(rng.uniform(0, 1, n), jnp.float32),
+            corr,
             jnp.asarray(rng.integers(-2, 3, n), jnp.int32),
         )
-    return w1, w2, w3, mk(e), mk(e, True), mk(co, True)
+    # expand: integer z_x*wsum term (the block input may carry a nonzero
+    # zero point); dw / project inputs are ReLU6 outputs (zero point 0)
+    zpc1 = 3 * w1.sum(0).astype(jnp.int32)
+    return (w1, w2, w3, mk(e, zpc1), mk(e, jnp.zeros(e, jnp.float32)),
+            mk(co, jnp.zeros(co, jnp.int32)))
 
 
 @pytest.mark.parametrize("h,w,c,e,co,s,res,bh", [
@@ -35,7 +38,7 @@ def test_fused_irb_matches_ref(h, w, c, e, co, s, res, bh):
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.integers(0, 16, (2, h, w, c)), jnp.int32)
     w1, w2, w3, (m1, c1, b1), (m2, c2, b2), (m3, c3, b3) = _mk(c, e, co)
-    rc = (0.5, 1.0, 0.9, -0.5) if res else None
+    rc = (-1.0, 0.5, 2.0, 0.9, 1) if res else None
     y = fused_irb_q(x, w1, m1, c1, b1, w2, m2, c2, b2, w3, m3, c3, b3,
                     stride=s, residual=res, res_consts=rc, block_h=bh,
                     interpret=True)

@@ -34,11 +34,11 @@ def _ref(x, w, mult, bias, wsum, zx, qmax):
 
 
 @pytest.mark.parametrize("shape,cin,cout,bm,bn,bk", [
-    ((2, 8, 8), 16, 32, 32, 32, 16),     # PW op on NHWC activations
+    ((2, 8, 8), 256, 256, 32, 128, 128), # PW op on NHWC: M, N, K all tiled
     ((2, 7, 7), 24, 56, 16, 128, 128),   # odd spatial -> M padding
     ((4,), 48, 10, 128, 128, 128),       # DENSE op on [B, C] (classifier)
-    ((1, 3, 5), 100, 36, 8, 32, 64),     # C_in/C_out with no 2^7 divisor
-    ((2, 6, 6), 8, 1280, 64, 128, 8),    # wide tail pw
+    ((1, 3, 5), 100, 36, 8, 128, 128),   # C_in/C_out with no 2^7 divisor
+    ((2, 6, 6), 8, 1280, 64, 128, 128),  # wide tail pw
 ])
 def test_pointwise_matches_int_pointwise(shape, cin, cout, bm, bn, bk):
     x, w, mult, zpc, bias, wsum, zx = _mk(shape, cin, cout)
@@ -54,7 +54,7 @@ def test_pointwise_nonzero_input_zero_point(zx):
     zpc = z_x * wsum correction must match the reference bit-for-bit."""
     x, w, mult, zpc, bias, wsum, jzx = _mk((2, 5, 5), 32, 24, zx=zx, seed=3)
     y = pointwise_conv_q(x, w, mult, zpc, bias, qmax=15, block_m=16,
-                         block_n=8, block_k=16, interpret=True)
+                         block_n=128, block_k=128, interpret=True)
     yr = _ref(x, w, mult, bias, wsum, jzx, 15)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))
 
@@ -65,7 +65,7 @@ def test_pointwise_bitwidth_sweep(act_bits):
     x, w, mult, zpc, bias, wsum, zx = _mk(
         (2, 6, 6), 16, 16, in_qmax=qmax, seed=1)
     y = pointwise_conv_q(x, w, mult, zpc, bias, qmax=qmax, block_m=32,
-                         block_n=16, block_k=16, interpret=True)
+                         block_n=128, block_k=128, interpret=True)
     yr = _ref(x, w, mult, bias, wsum, zx, qmax)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))
     assert 0 <= int(y.min()) and int(y.max()) <= qmax
@@ -75,7 +75,7 @@ def test_pointwise_no_clip_linear_output():
     x, w, mult, zpc, bias, wsum, zx = _mk((2, 4, 4), 16, 8, seed=2)
     bias = bias - 10  # force negatives through
     y = pointwise_conv_q(x, w, mult, zpc, bias, qmax=15, clip=False,
-                         block_m=16, block_n=8, block_k=16, interpret=True)
+                         block_m=16, block_n=128, block_k=128, interpret=True)
     acc = int_pointwise(x, w)
     yr = jnp.round(acc.astype(jnp.float32) * mult).astype(jnp.int32) + bias
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))
@@ -94,6 +94,6 @@ def test_property_pointwise_vs_int_pointwise(h, b, cin, cout, act_bits, seed):
     x, w, mult, zpc, bias, wsum, zx = _mk(
         (b, h, h), cin, cout, in_qmax=qmax, seed=seed)
     y = pointwise_conv_q(x, w, mult, zpc, bias, qmax=qmax, block_m=32,
-                         block_n=32, block_k=32, interpret=True)
+                         block_n=128, block_k=128, interpret=True)
     yr = _ref(x, w, mult, bias, wsum, zx, qmax)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(yr))

@@ -58,7 +58,7 @@ def test_fixed_point_requant_matches_float():
     mult = rng.uniform(1e-5, 0.5, (256,))
     mant, shift = quantize_multiplier(mult)
     y_float = requantize_float(acc, jnp.asarray(mult, jnp.float32))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         y_fxp = requantize_fixedpoint(
             acc.astype(jnp.int64), jnp.asarray(mant), jnp.asarray(shift))
     # mantissa has 31 bits: agree within 1 ULP of the requantized grid
