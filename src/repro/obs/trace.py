@@ -19,7 +19,9 @@ Design constraints, in order:
   * **Cheap when off.** `NULL` is a no-op tracer that is falsy; hot-path
     call sites guard their extra clock reads with `if tracer:` so a
     tracing-disabled engine performs exactly the clock reads it always did.
-  * **Zero dependencies.** Events are plain dicts; export is `json.dump`.
+  * **Cheap when on.** Events are flat tuples until export (see `Tracer`);
+    the collector stops walking them after one collection.
+  * **Zero dependencies.** Export is plain dicts through `json.dump`.
 
 Event vocabulary (all standard trace-event phases):
 
@@ -36,7 +38,7 @@ from __future__ import annotations
 import json
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # Well-known track ids for the serving pipeline (metadata-named on first
 # use; stage executors get TID_STAGE0 + stage index).
@@ -91,6 +93,16 @@ class NullTracer:
 
 NULL = NullTracer()
 
+# (phase, name, cat, tid, t0_s, t1_s, async id, key1, value1, key2, ...)
+_Event = Tuple[Any, ...]
+
+
+def _flat(args: Optional[Dict[str, Any]]) -> Tuple[Any, ...]:
+    """Args as alternating keys and values, to append to the event tuple:
+    a collection untracks a tuple only once nothing in it is tracked, one
+    level per pass, so the event stays one level deep."""
+    return sum(args.items(), ()) if args else ()
+
 
 class Tracer:
     """Collects trace events; `to_chrome()`/`save()` export Perfetto JSON.
@@ -100,15 +112,34 @@ class Tracer:
     subtracts its construction-time origin and scales to microseconds (the
     trace-event unit). `pid` tags every event (one tracer per process is
     the normal shape; a shared tracer across engines puts them on one
-    timeline, which is exactly what the multi-model router wants)."""
+    timeline, which is exactly what the multi-model router wants).
+
+    Each event is stored as one flat tuple of atoms (`_Event`: phase, name,
+    category, track, raw times, async id, then arg keys and values in
+    turn); Chrome dicts are built only on export. A tuple that holds no
+    container the collector tracks is untracked at its first garbage
+    collection, so a long traced run leaves the collector almost nothing
+    to walk (call sites keep arg values to numbers, strings and, once per
+    micro-batch, a tuple of request ids, untracked one pass later).
+
+    With the default clock the tracer also notes its origin on the wall
+    clock (`origin_unix_ns`, exported as `otherData.origin_unix_ns`):
+    `origin_unix_ns + ts * 1e3` puts a span on the `time.time_ns` clock a
+    profiler session is stamped with. An injected clock has no wall-clock
+    meaning, so fake-clock exports carry no such field."""
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
                  *, process_name: str = "repro-serve", pid: int = 0,
                  origin_s: Optional[float] = None):
         self._clock = time.perf_counter if clock is None else clock
         self._origin = self._clock() if origin_s is None else origin_s
+        self.origin_unix_ns: Optional[int] = None
+        if clock is None:
+            wall = time.time_ns()
+            self.origin_unix_ns = wall - round(
+                (time.perf_counter() - self._origin) * 1e9)
         self.pid = pid
-        self.events: List[Dict[str, Any]] = []
+        self.events: List[_Event] = []
         self._tracks: Dict[int, str] = {}
         self._meta: List[Dict[str, Any]] = [{
             "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
@@ -123,10 +154,6 @@ class Tracer:
 
     def now(self) -> float:
         return self._clock()
-
-    def _ts(self, t_s: Optional[float]) -> float:
-        t = self._clock() if t_s is None else t_s
-        return (t - self._origin) * 1e6
 
     # -- record methods ----------------------------------------------------
 
@@ -143,56 +170,36 @@ class Tracer:
                  cat: str = "", tid: int = TID_ENGINE,
                  args: Optional[Dict[str, Any]] = None) -> None:
         """One finished span with explicit start/end times ("X" event)."""
-        ev: Dict[str, Any] = {
-            "ph": "X", "name": name, "cat": cat, "pid": self.pid,
-            "tid": tid, "ts": self._ts(start_s),
-            "dur": max(0.0, (end_s - start_s) * 1e6),
-        }
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        self.events.append(("X", name, cat, tid, start_s, end_s, None,
+                            *_flat(args)))
 
     def instant(self, name: str, t_s: Optional[float] = None, *,
                 cat: str = "", tid: int = TID_ENGINE,
                 args: Optional[Dict[str, Any]] = None) -> None:
-        ev: Dict[str, Any] = {
-            "ph": "i", "name": name, "cat": cat, "pid": self.pid,
-            "tid": tid, "ts": self._ts(t_s), "s": "t",
-        }
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        t = self._clock() if t_s is None else t_s
+        self.events.append(("i", name, cat, tid, t, None, None, *_flat(args)))
 
     def counter(self, name: str, values: Dict[str, float],
                 t_s: Optional[float] = None, *, tid: int = TID_ENGINE) -> None:
-        self.events.append({
-            "ph": "C", "name": name, "pid": self.pid, "tid": tid,
-            "ts": self._ts(t_s), "args": dict(values),
-        })
+        t = self._clock() if t_s is None else t_s
+        self.events.append(("C", name, "", tid, t, None, None,
+                            *_flat(values)))
 
     def async_begin(self, name: str, span_id: int,
                     t_s: Optional[float] = None, *, cat: str = "request",
                     args: Optional[Dict[str, Any]] = None) -> None:
         """Open an async span (nestable "b"); pairs with `async_end` by
         (cat, id) — the per-request lifecycle span, one id per rid."""
-        ev: Dict[str, Any] = {
-            "ph": "b", "name": name, "cat": cat, "id": span_id,
-            "pid": self.pid, "tid": TID_REQUESTS, "ts": self._ts(t_s),
-        }
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        t = self._clock() if t_s is None else t_s
+        self.events.append(("b", name, cat, TID_REQUESTS, t, None, span_id,
+                            *_flat(args)))
 
     def async_end(self, name: str, span_id: int,
                   t_s: Optional[float] = None, *, cat: str = "request",
                   args: Optional[Dict[str, Any]] = None) -> None:
-        ev: Dict[str, Any] = {
-            "ph": "e", "name": name, "cat": cat, "id": span_id,
-            "pid": self.pid, "tid": TID_REQUESTS, "ts": self._ts(t_s),
-        }
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
+        t = self._clock() if t_s is None else t_s
+        self.events.append(("e", name, cat, TID_REQUESTS, t, None, span_id,
+                            *_flat(args)))
 
     @contextmanager
     def span(self, name: str, *, cat: str = "", tid: int = TID_ENGINE,
@@ -208,13 +215,36 @@ class Tracer:
 
     # -- export ------------------------------------------------------------
 
+    def _chrome(self, event: _Event) -> Dict[str, Any]:
+        ph, name, cat, tid, t0, t1, span_id = event[:7]
+        ts = (t0 - self._origin) * 1e6
+        if ph == "X":
+            ev: Dict[str, Any] = {
+                "ph": ph, "name": name, "cat": cat, "pid": self.pid,
+                "tid": tid, "ts": ts, "dur": max(0.0, (t1 - t0) * 1e6)}
+        elif ph == "i":
+            ev = {"ph": ph, "name": name, "cat": cat, "pid": self.pid,
+                  "tid": tid, "ts": ts, "s": "t"}
+        elif ph == "C":
+            return {"ph": ph, "name": name, "pid": self.pid, "tid": tid,
+                    "ts": ts, "args": dict(zip(event[7::2], event[8::2]))}
+        else:  # "b" / "e"
+            ev = {"ph": ph, "name": name, "cat": cat, "id": span_id,
+                  "pid": self.pid, "tid": tid, "ts": ts}
+        if len(event) > 7:
+            ev["args"] = dict(zip(event[7::2], event[8::2]))
+        return ev
+
     def to_chrome(self) -> Dict[str, Any]:
         """The Perfetto-loadable document: metadata first (track names),
         then events in record order (the format does not require sorting)."""
-        return {
-            "traceEvents": self._meta + self.events,
+        doc: Dict[str, Any] = {
+            "traceEvents": self._meta + [self._chrome(e) for e in self.events],
             "displayTimeUnit": "ms",
         }
+        if self.origin_unix_ns is not None:
+            doc["otherData"] = {"origin_unix_ns": self.origin_unix_ns}
+        return doc
 
     def save(self, path: str) -> str:
         with open(path, "w") as f:

@@ -38,6 +38,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import math
+import operator
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -81,6 +82,14 @@ class VisionRequest:
     # governor may shed requests at or below the engine's shed class;
     # work above it is only ever deferred, never dropped.
     slo: int = 0
+
+
+class MicroBatch(list):
+    """The live requests of one micro-batch, numbered per engine (`id`,
+    counting from 0): the pipeline's tag, and the `batch` arg of every span
+    the micro-batch causes."""
+
+    __slots__ = ("id",)
 
 
 @dataclasses.dataclass
@@ -247,8 +256,9 @@ class VisionEngine:
 
     def _init_obs(self) -> None:
         """Register instruments, arm retrace-leak detection, name the trace
-        tracks, and tie stage dispatch spans back to request ids."""
+        tracks, and tag stage dispatch and harvest spans with batch ids."""
         reg, lbl = self._reg, {"model": self.name}
+        self._request_cat = f"request:{self.name}"  # per-model async spans
         self._m_submitted = reg.counter(
             "serve_requests_submitted_total", "requests admitted", labels=lbl)
         self._m_expired = reg.counter(
@@ -306,7 +316,7 @@ class VisionEngine:
             self.tracer.name_track(OT.TID_ENGINE, "engine")
             self.tracer.name_track(OT.TID_REQUESTS, "requests")
             self.tracer.name_track(OT.TID_SCHED, "scheduler")
-            self.pipe.tag_info = lambda reqs: {"rids": [r.rid for r in reqs]}
+            self.pipe.batch_id = operator.attrgetter("id")
 
     def _note_retrace(self, metric) -> Callable:
         def _hook(stage: CompiledStage, shape: Tuple[int, ...]) -> None:
@@ -354,11 +364,8 @@ class VisionEngine:
             # closed at expiry or completion); arrival is already read —
             # no extra clock reads on the admission path
             self.tracer.async_begin(
-                "request", rid, arrival, cat=f"request:{self.name}",
+                "request", rid, arrival, cat=self._request_cat,
                 args={"model": self.name, "deadline_s": deadline_s})
-            self.tracer.counter(
-                f"queue_depth:{self.name}", {"pending": len(self._queue)},
-                arrival)
         return rid
 
     def pending(self) -> int:
@@ -382,7 +389,7 @@ class VisionEngine:
             return jnp.asarray(x)
         return jax.device_put(x, self._batch_sharding)
 
-    def _form_batches(self) -> Iterator[Tuple[List[VisionRequest], jax.Array]]:
+    def _form_batches(self) -> Iterator[Tuple[MicroBatch, jax.Array]]:
         """Drain the queue into bucket-padded micro-batches, EDF-ordered.
 
         Lazily, one micro-batch per next() — so under the pipelined
@@ -397,7 +404,7 @@ class VisionEngine:
         head = 0
         while head < len(pending):
             now = self._clock()
-            live: List[VisionRequest] = []
+            live = MicroBatch()
             while head < len(pending) and len(live) < self.buckets[-1]:
                 req = pending[head]
                 head += 1
@@ -409,7 +416,7 @@ class VisionEngine:
                     if self.tracer:
                         self.tracer.async_end(
                             "request", req.rid, now,
-                            cat=f"request:{self.name}",
+                            cat=self._request_cat,
                             args={"status": "expired"})
                     continue
                 live.append(req)
@@ -431,6 +438,7 @@ class VisionEngine:
             x = np.zeros((bucket, *self.input_shape), np.float32)
             for i, req in enumerate(live):
                 x[i] = req.image
+            live.id = self._micro_batches
             self._micro_batches += 1
             self._rows += bucket
             self._pad_rows += bucket - len(live)
@@ -439,24 +447,31 @@ class VisionEngine:
             self._m_pad.inc(bucket - len(live))
             for req in live:
                 self._m_qwait.observe(now - req.arrival_s)
-            if self.tracer:
-                # batch-formation span covers the host-side gather+pad; the
-                # per-request queue waits nest as b/e pairs on timestamps
-                # already read (arrival, now) — zero extra clock reads
-                tf1 = self._clock()
-                self.tracer.complete(
-                    "form_batch", now, tf1, cat="pipeline", tid=OT.TID_SCHED,
-                    args={"model": self.name, "bucket": bucket,
-                          "live": len(live), "pad": bucket - len(live),
-                          "rids": [r.rid for r in live]})
-                for req in live:
-                    self.tracer.async_begin(
-                        "queue_wait", req.rid, req.arrival_s,
-                        cat=f"request:{self.name}")
-                    self.tracer.async_end(
-                        "queue_wait", req.rid, now,
-                        cat=f"request:{self.name}")
-            yield live, self._place(x)
+            if not self.tracer:
+                yield live, self._place(x)
+                continue
+            # batch-formation span covers the host-side gather+pad and
+            # holds the batch's request ids (the request -> batch link);
+            # the per-request queue waits nest as b/e pairs on timestamps
+            # already read (arrival, now); `place` times the upload
+            self.tracer.complete(
+                "form_batch", now, self._clock(), cat="pipeline",
+                tid=OT.TID_SCHED,
+                args={"model": self.name, "bucket": bucket,
+                      "live": len(live), "pad": bucket - len(live),
+                      "batch": live.id, "rids": tuple(r.rid for r in live)})
+            for req in live:
+                self.tracer.async_begin(
+                    "queue_wait", req.rid, req.arrival_s,
+                    cat=self._request_cat)
+                self.tracer.async_end(
+                    "queue_wait", req.rid, now, cat=self._request_cat)
+            tp0 = self._clock()
+            xd = self._place(x)
+            self.tracer.complete(
+                "place", tp0, self._clock(), cat="pipeline",
+                tid=OT.TID_SCHED, args={"batch": live.id, "rows": bucket})
+            yield live, xd
 
     def _shed_or_defer(self, live: List[VisionRequest],
                        rest: List[VisionRequest], now: float) -> None:
@@ -471,7 +486,7 @@ class VisionEngine:
                 self._m_shed.inc()
                 if self.tracer:
                     self.tracer.async_end(
-                        "request", req.rid, now, cat=f"request:{self.name}",
+                        "request", req.rid, now, cat=self._request_cat,
                         args={"status": "shed"})
             else:
                 deferred.append(req)
@@ -495,9 +510,10 @@ class VisionEngine:
     # serving
     # ------------------------------------------------------------------
 
-    def _record_batch(self, reqs: List[VisionRequest], y: jax.Array,
+    def _record_batch(self, reqs: MicroBatch, y: jax.Array,
                       done: float) -> None:
-        """Un-pad a finished micro-batch into per-request results."""
+        """Un-pad a finished micro-batch into per-request results (traced
+        as `record`, from `done`: the logits download and the un-pad)."""
         logits = np.asarray(y)
         for i, req in enumerate(reqs):
             self._results[req.rid] = RequestResult(
@@ -508,8 +524,13 @@ class VisionEngine:
             self._m_latency.observe(done - req.arrival_s)
             if self.tracer:
                 self.tracer.async_end(
-                    "request", req.rid, done, cat=f"request:{self.name}",
+                    "request", req.rid, done, cat=self._request_cat,
                     args={"status": "ok"})
+        if self.tracer:
+            self.tracer.complete(
+                "record", done, self._clock(), cat="pipeline",
+                tid=OT.TID_SCHED,
+                args={"batch": reqs.id, "rows": int(logits.shape[0])})
 
     def _collect_results(self) -> Dict[int, RequestResult]:
         results, self._results = self._results, {}

@@ -18,31 +18,23 @@ Observability (`tracer=` / `metrics=`, see `repro.obs`): each stage
 dispatch becomes a span on that CU's trace track (dispatch/enqueue time —
 XLA dispatch is asynchronous, so stage *compute* shows up as harvest wait
 at the sync point, which is also traced), plus per-stage dispatch-seconds
-and bytes-moved instruments and a harvest-wait histogram. All extra clock
-reads are guarded by `if tracer` / registered-instrument no-ops: with
+instruments and a harvest-wait histogram; dispatch and harvest spans carry
+the micro-batch's `batch` id when the caller installs `batch_id`. All extra
+clock reads are guarded by `if tracer` / registered-instrument no-ops: with
 observability off the executor performs exactly the clock reads it always
 did (fake-clock tests stay bitwise).
 """
 from __future__ import annotations
 
 import time
-from typing import Any, Iterable, Iterator, List, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, Iterator, List,
+                    Optional, Tuple)
 
 import jax
 
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
 from repro.serve.vision.stages import CompiledStage
-
-
-def _stage_bytes_per_row(stage: CompiledStage) -> int:
-    """Analytic uint8 activation traffic of one batch row through a stage:
-    input read + output write at the stage boundary (the DDR view of the
-    paper's CU invocation; intra-stage intermediates stay 'on-chip')."""
-    sig = stage.spec.signature
-    n_in = (sig.in_hw or 1) * (sig.in_hw or 1) * sig.in_ch
-    n_out = (sig.out_hw or 1) * (sig.out_hw or 1) * sig.out_ch
-    return n_in + n_out
 
 
 class PipelinedExecutor:
@@ -60,10 +52,10 @@ class PipelinedExecutor:
         # wall time spent blocked on finished outputs (pipeline stall proxy)
         self.harvest_wait_s = 0.0
         self.tracer = tracer if tracer is not None else OT.NULL
-        # optional tag -> trace-args hook: the engine installs one mapping
-        # its (reqs, x) batch tags to request ids, tying every stage
-        # dispatch span back to the requests riding the micro-batch
-        self.tag_info = None
+        # optional tag -> micro-batch id hook: the engine installs one so
+        # every dispatch and harvest span names the batch it served (the
+        # engine's form_batch span ties that id to request ids)
+        self.batch_id: Optional[Callable[[Any], int]] = None
         reg = metrics if metrics is not None else OM.NULL_REGISTRY
         self._m_harvest = reg.histogram(
             "serve_harvest_wait_seconds",
@@ -71,19 +63,12 @@ class PipelinedExecutor:
             "only sync point)")
         self._m_ticks = reg.counter(
             "serve_pipeline_ticks_total", "scheduler ticks advanced")
-        self._stage_row_bytes = [_stage_bytes_per_row(s) for s in stages]
         self._m_stage_dispatch = []
-        self._m_stage_bytes = []
         for i, stage in enumerate(stages):
             cu = stage.spec.cu
-            lbl = {"cu": cu}
             self._m_stage_dispatch.append(reg.histogram(
                 "serve_stage_dispatch_seconds",
-                "per-stage dispatch (enqueue) wall time", labels=lbl))
-            self._m_stage_bytes.append(reg.counter(
-                "serve_stage_bytes_moved_total",
-                "analytic uint8 activation bytes in+out of the stage",
-                labels=lbl))
+                "per-stage dispatch (enqueue) wall time", labels={"cu": cu}))
             if self.tracer:
                 self.tracer.name_track(OT.TID_STAGE0 + i, f"stage:{cu}")
 
@@ -110,21 +95,17 @@ class PipelinedExecutor:
                 continue
             tag, x = self._slots[i]
             self._slots[i] = None
-            rows = int(x.shape[0])
             if self.tracer:
                 t0 = self._clock()
                 y = self.stages[i](x)  # async dispatch — returns immediately
                 t1 = self._clock()
-                args = {"rows": rows}
-                if self.tag_info is not None:
-                    args.update(self.tag_info(tag))
                 self.tracer.complete(
                     f"dispatch:{self.stages[i].spec.cu}", t0, t1,
-                    cat="stage", tid=OT.TID_STAGE0 + i, args=args)
+                    cat="stage", tid=OT.TID_STAGE0 + i,
+                    args=self._span_args(tag, rows=int(x.shape[0])))
                 self._m_stage_dispatch[i].observe(t1 - t0)
             else:
                 y = self.stages[i](x)  # async dispatch — returns immediately
-            self._m_stage_bytes[i].inc(rows * self._stage_row_bytes[i])
             if i + 1 < self.depth:
                 self._slots[i + 1] = (tag, y)
             else:
@@ -151,8 +132,14 @@ class PipelinedExecutor:
         self._m_harvest.observe(t1 - t0)
         if self.tracer:
             self.tracer.complete("harvest", t0, t1, cat="pipeline",
-                                 tid=OT.TID_SCHED)
+                                 tid=OT.TID_SCHED,
+                                 args=self._span_args(finished[0]))
         return finished
+
+    def _span_args(self, tag: Any, **args: Any) -> Dict[str, Any]:
+        if self.batch_id is not None:
+            args["batch"] = self.batch_id(tag)
+        return args
 
     # -- streaming driver ---------------------------------------------------
 

@@ -109,7 +109,21 @@ class CompiledStage:
             self._body = jax.shard_map(
                 self._compute, mesh=mesh, in_specs=P("data"),
                 out_specs=P("data"), check_vma=False)
-        self._fn = jax.jit(self._trace, **jit_kwargs)
+        self._fn = jax.jit(self._named(), **jit_kwargs)
+
+    def _named(self) -> Callable[[jax.Array], jax.Array]:
+        """`_trace` as a function named `stage_<cu>` under the scope `<cu>`:
+        each stage's device program is then the XLA module
+        `jit_stage_<cu>`, its ops' metadata sit under `<cu>/`, and kernel
+        names stay those of the kernels."""
+        cu_name = self.spec.cu
+
+        def stage(x: jax.Array) -> jax.Array:
+            with jax.named_scope(cu_name):
+                return self._trace(x)
+
+        stage.__name__ = stage.__qualname__ = f"stage_{cu_name}"
+        return stage
 
     def _trace(self, x: jax.Array) -> jax.Array:
         self.traces += 1

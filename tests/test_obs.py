@@ -64,15 +64,92 @@ def test_tracer_deterministic_under_fake_clock():
     assert docs[0] == docs[1]
 
 
+def _exported(tracer):
+    return [ev for ev in tracer.to_chrome()["traceEvents"] if ev["ph"] != "M"]
+
+
 def test_tracer_timebase_microseconds_from_origin():
     tracer = Tracer(FakeClock(), origin_s=10.0)
     tracer.complete("work", 10.5, 10.75)
-    (ev,) = tracer.events
+    (ev,) = _exported(tracer)
     assert ev["ts"] == pytest.approx(0.5e6)
     assert ev["dur"] == pytest.approx(0.25e6)
     # inverted span (clock skew between explicit stamps) clamps, not negates
     tracer.complete("skew", 11.0, 10.0)
-    assert tracer.events[-1]["dur"] == 0.0
+    assert _exported(tracer)[-1]["dur"] == 0.0
+
+
+def test_tracer_export_keeps_chrome_shape():
+    """Events stored as tuples export as the same Chrome dicts, key order
+    included: fake-clock documents stay byte-for-byte what they were."""
+    tracer = Tracer(FakeClock(step=0.125), origin_s=0.0)
+    _record_session(tracer)
+    got = json.dumps(_exported(tracer))
+    want = json.dumps([
+        {"ph": "X", "name": "form_batch", "cat": "pipeline", "pid": 0,
+         "tid": OT.TID_SCHED, "ts": 1e6, "dur": 0.5e6,
+         "args": {"bucket": 4}},
+        {"ph": "i", "name": "retrace:Body", "cat": "retrace", "pid": 0,
+         "tid": OT.TID_ENGINE, "ts": 2e6, "s": "t"},
+        {"ph": "C", "name": "queue_depth", "pid": 0, "tid": OT.TID_ENGINE,
+         "ts": 2.5e6, "args": {"pending": 3}},
+        {"ph": "b", "name": "request", "cat": "request:m", "id": 7,
+         "pid": 0, "tid": OT.TID_REQUESTS, "ts": 3e6},
+        {"ph": "e", "name": "request", "cat": "request:m", "id": 7,
+         "pid": 0, "tid": OT.TID_REQUESTS, "ts": 4e6,
+         "args": {"status": "ok"}},
+        {"ph": "X", "name": "tune:dw", "cat": "tune", "pid": 0,
+         "tid": OT.TID_TUNE, "ts": 0.125e6, "dur": 0.125e6},
+    ])
+    assert got == want
+    assert "otherData" not in tracer.to_chrome()
+
+
+def test_tracer_events_untracked_after_collection():
+    """Stored events hold no container the collector tracks: after one
+    collection none of them is walked again, however long the run."""
+    import gc
+
+    tracer = Tracer(FakeClock(step=1e-3), origin_s=0.0)
+    for b in range(50):
+        tracer.complete("form_batch", 1.0, 1.5, cat="pipeline",
+                        tid=OT.TID_SCHED,
+                        args={"bucket": 8, "batch": b,
+                              "rids": tuple(range(8 * b, 8 * b + 8))})
+        tracer.complete("dispatch:head", 1.5, 1.6, cat="stage",
+                        args={"rows": 8, "batch": b})
+        tracer.async_begin("request", b, 1.0, cat="request:m",
+                           args={"model": "m", "deadline_s": None})
+        tracer.async_end("request", b, 2.0, cat="request:m",
+                         args={"status": "ok"})
+        tracer.instant("router_dispatch", cat="router")
+    assert len(tracer.events) == 250
+    gc.collect()
+    # flat events go at the first collection; a form_batch event holds
+    # its request-id tuple, which goes first, and the event at the next
+    tracked = [ev[1] for ev in tracer.events if gc.is_tracked(ev)]
+    assert set(tracked) <= {"form_batch"}
+    gc.collect()
+    assert not any(gc.is_tracked(ev) for ev in tracer.events)
+
+
+def test_tracer_exports_wall_clock_origin():
+    """A default-clock tracer notes its origin on the wall clock, so its
+    spans can be laid on a profiler session's `time.time_ns` clock; a
+    fake clock has no wall-clock meaning and exports none."""
+    import time
+
+    before = time.time_ns()
+    tracer = Tracer()
+    after = time.time_ns()
+    origin = tracer.to_chrome()["otherData"]["origin_unix_ns"]
+    assert isinstance(origin, int)
+    assert before - 5_000_000 <= origin <= after + 5_000_000
+    # an explicit origin on the default clock shifts the wall origin too
+    t = time.perf_counter()
+    shifted = Tracer(origin_s=t - 2.0).origin_unix_ns
+    assert abs(shifted - (time.time_ns() - 2_000_000_000)) < 5_000_000
+    assert "otherData" not in Tracer(FakeClock(), origin_s=0.0).to_chrome()
 
 
 def test_tracer_export_and_validate():

@@ -128,6 +128,66 @@ def test_trace_covers_every_request_lifecycle(mnv2_qnet):
     json.dumps(snap, allow_nan=False)
 
 
+def test_per_batch_spans_share_the_batch_id(mnv2_qnet):
+    """A micro-batch's host life reads form_batch -> place -> dispatch:<cu>
+    ... -> harvest -> record, every span carrying the batch's id; only
+    form_batch lists the request ids."""
+    doc, _, results = _traced_drain(mnv2_qnet, n=6)
+    spans = [ev for ev in doc["traceEvents"] if ev["ph"] == "X"]
+    form = [ev for ev in spans if ev["name"] == "form_batch"]
+    ids = [ev["args"]["batch"] for ev in form]
+    assert ids == [0, 1, 2]
+    assert sorted(r for ev in form for r in ev["args"]["rids"]) == sorted(results)
+    n_stages = len({ev["name"] for ev in spans
+                    if ev["name"].startswith("dispatch:")})
+    for b in ids:
+        mine = [ev for ev in spans if ev.get("args", {}).get("batch") == b]
+        names = [ev["name"] for ev in mine]
+        assert names.count("form_batch") == 1
+        assert names.count("place") == 1 and names.count("record") == 1
+        assert names.count("harvest") == 1
+        assert sum(n.startswith("dispatch:") for n in names) == n_stages
+        order = sorted(mine, key=lambda ev: ev["ts"])
+        assert [ev["name"] for ev in order][:2] == ["form_batch", "place"]
+        assert [ev["name"] for ev in order][-2:] == ["harvest", "record"]
+        assert all("rids" not in ev["args"] for ev in mine
+                   if ev["name"] != "form_batch")
+        assert all(ev["args"]["rows"] == 2 for ev in mine
+                   if ev["name"] in ("place", "record"))
+    for name in ("place", "harvest", "record"):
+        assert len([ev for ev in spans if ev["name"] == name]) == len(ids)
+    # no per-submit counter events any more
+    assert not [ev for ev in doc["traceEvents"] if ev["ph"] == "C"]
+
+
+class CountingClock(FakeClock):
+    def __init__(self):
+        super().__init__(step=1e-3)
+        self.reads = 0
+
+    def __call__(self) -> float:
+        self.reads += 1
+        return super().__call__()
+
+
+def test_tracing_off_reads_the_clock_as_before(mnv2_qnet):
+    """With tracing off, a drain reads the clock once per submit, twice
+    around the drain, and per micro-batch once to form it, once when it is
+    recorded and twice around the harvest: the spans cost nothing off."""
+    clock = CountingClock()
+    eng = VisionEngine(mnv2_qnet, buckets=(2,), clock=clock, name="m")
+    for img in _images(6):
+        eng.submit(img)
+    eng.run()
+    assert clock.reads == 6 + 2 + 3 * 4
+    clock.reads = 0
+    mm = MultiModelEngine({"m": eng})
+    for img in _images(4):
+        mm.submit("m", img)
+    mm.run()
+    assert clock.reads == 4 + 2 + 2 * 4
+
+
 def test_obs_on_is_bit_exact(mnv2_qnet):
     imgs = _images(4)
     plain = VisionEngine(mnv2_qnet, buckets=(2,))
