@@ -10,7 +10,8 @@ Multi-replica sharded serving (split micro-batches across N CPU devices):
 
 Walks the full deployment path from the paper: build the NetSpec, calibrate
 activations, quantize to an integer QNet, compile the CU schedule into
-stage executors, then serve a stream of requests with continuous batching —
+stage executors chained into one program per batch bucket, then serve a
+stream of requests with continuous batching —
 and shows the engine output is bit-exact with the reference integer runner.
 When more than one device is visible, the engine shards every micro-batch
 data-parallel across a `dist.sharding.data_mesh`; the logits stay

@@ -6,10 +6,12 @@ Layers, bottom-up:
 
   * `stages`   — the stage compiler: lowers a `CUPlan` schedule into one
                  jitted, bucket-batched executor per CU role
-                 (Head / Body / Tail / Classifier).
-  * `pipeline` — the software-pipelined scheduler: streams micro-batches
-                 through the CU stages with every stage in flight at once
-                 (the paper's double-buffered CU invocation schedule).
+                 (Head / Body / Tail / Classifier), and chains them into
+                 the one program per bucket the engine serves
+                 (`CompiledNet`).
+  * `pipeline` — the scheduler: streams micro-batches through the
+                 programs with two in flight (the paper's double-buffered
+                 CU invocation schedule).
   * `engine`   — the continuous-batching front end: request queue, dynamic
                  batch former with shape/bucket admission, per-request
                  deadlines, and throughput/latency/energy-proxy stats
@@ -28,10 +30,12 @@ from repro.serve.vision.engine import (
     VisionRequest,
 )
 from repro.serve.vision.pipeline import PipelinedExecutor
-from repro.serve.vision.stages import CompiledStage, compile_stages
+from repro.serve.vision.stages import (CompiledNet, CompiledStage,
+                                      compile_stages)
 
 __all__ = [
     "AdmissionError",
+    "CompiledNet",
     "CompiledStage",
     "EngineStats",
     "MultiModelEngine",

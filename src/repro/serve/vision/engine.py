@@ -2,8 +2,9 @@
 
 Requests (single images) enter a queue; the dynamic batch former groups them
 into bucket-sized micro-batches (earliest-deadline-first), pads odd tails up
-to the nearest bucket so every stage executor sees one of a fixed set of
-batch shapes, and feeds the software-pipelined CU executor. Results are
+to the nearest bucket so the served program sees one of a fixed set of
+batch shapes, and feeds the executor, which launches the whole CU chain as
+one program per micro-batch with two micro-batches in flight. Results are
 un-padded back to per-request logits with latency accounting.
 
 Admission control mirrors what a fixed-function accelerator can accept:
@@ -15,7 +16,7 @@ Two scaling axes beyond the single-device engine:
 
   * **replication** (`mesh=`): a 1-D 'data' mesh from `dist.sharding`
     replicates the whole integer datapath — constants on every replica,
-    micro-batch rows sharded along 'data' through every stage executor
+    micro-batch rows sharded along 'data' through the served program
     (`jax.jit` with `NamedSharding` in/out). The multi-device analogue of
     DeepDive's parallel channel/filter CU replication; results stay
     bit-exact because every image's arithmetic is replica-local.
@@ -54,7 +55,8 @@ from repro.energy import EnergyReport, PowerGovernor, PowerModel, estimate_energ
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
 from repro.serve.vision.pipeline import PipelinedExecutor
-from repro.serve.vision.stages import CompiledStage, compile_stages
+from repro.serve.vision.stages import (CompiledNet, CompiledStage,
+                                      compile_stages)
 
 
 def _percentile(sorted_lat: Sequence[float], p: float) -> float:
@@ -136,7 +138,8 @@ class EngineStats:
 
 
 class VisionEngine:
-    """Serve a calibrated QNet through the pipelined CU stage executors.
+    """Serve a calibrated QNet through the CU stages, chained into one
+    jitted program per bucket (`net`, a `CompiledNet` over `stages`).
 
     `mesh`: a 1-D 'data' mesh (see `dist.sharding.data_mesh`) shards every
     micro-batch data-parallel across its replicas; each requested bucket is
@@ -181,7 +184,6 @@ class VisionEngine:
         body_fast_path: str = "auto",
         op_kernels: str = "auto",
         prepare: bool = True,
-        donate: str = "auto",
         interpret: Optional[bool] = None,
         mesh=None,
         tuned=None,
@@ -217,13 +219,16 @@ class VisionEngine:
         self.stages: List[CompiledStage] = compile_stages(
             qnet, self.plan, fixed_point=fixed_point, input_bits=input_bits,
             body_fast_path=body_fast_path, op_kernels=op_kernels,
-            prepare=prepare, donate=donate, interpret=interpret, mesh=mesh,
+            prepare=prepare, interpret=interpret, mesh=mesh,
             tuned=tuned)
+        # the served program: every CU stage chained into one launch per
+        # micro-batch (the per-CU programs stay the lowering unit)
+        self.net = CompiledNet(self.stages)
         self.name = name
         self.tracer = tracer if tracer is not None else OT.NULL
         self.metrics = metrics
         self._reg = metrics if metrics is not None else OM.NULL_REGISTRY
-        self.pipe = PipelinedExecutor(self.stages, clock=self._clock,
+        self.pipe = PipelinedExecutor([self.net], clock=self._clock,
                                       tracer=tracer, metrics=metrics)
         net = qnet.spec
         self.input_shape = net.input_shape()  # (H, W, C) or (T, C)
@@ -302,11 +307,12 @@ class VisionEngine:
             "serve_requests_deferred_total",
             "requests deferred to a later run() by the power governor",
             labels=lbl)
-        # retrace-leak detection: every stage knows the legal batch shapes
-        # (the padded buckets); a trace outside them is a leak past the
-        # batch former — counted, warned, and surfaced in stats()
+        # retrace-leak detection: the served program and every per-CU stage
+        # know the legal batch shapes (the padded buckets); a trace outside
+        # them is a leak past the batch former — counted, warned, and
+        # surfaced in stats()
         allowed = frozenset(self.buckets)
-        for st in self.stages:
+        for st in (self.net, *self.stages):
             st.allowed_batches = allowed
             st.on_retrace = self._note_retrace(reg.counter(
                 "serve_stage_retraces_total",
@@ -537,7 +543,7 @@ class VisionEngine:
         return results
 
     def run(self) -> Dict[int, RequestResult]:
-        """Drain the queue through the pipelined CU stages; return results
+        """Drain the queue through the served program; return results
         (keyed by request id) for everything completed by this call."""
         t0 = self._clock()
         for reqs, y in self.pipe.stream(self._form_batches()):
@@ -551,8 +557,8 @@ class VisionEngine:
         return self._collect_results()
 
     def warmup(self) -> None:
-        """Pre-trace every stage at every bucket size (avoids paying XLA
-        tracing on the serving path)."""
+        """Pre-trace the served program at every bucket size (avoids
+        paying XLA tracing on the serving path)."""
         for b in self.buckets:
             self.pipe.warmup(
                 self._place(np.zeros((b, *self.input_shape), np.float32)))
@@ -584,8 +590,9 @@ class VisionEngine:
             latency_p95_s=_percentile(lat, 0.95),
             micro_batches=self._micro_batches,
             pad_fraction=(self._pad_rows / self._rows) if self._rows else 0.0,
+            # one served launch runs every CU once
             stage_invocations={
-                s.spec.cu: s.invocations for s in self.stages},
+                s.spec.cu: self.net.invocations for s in self.stages},
             harvest_wait_s=self.pipe.harvest_wait_s,
             macs_per_image=macs,
             energy_j_per_image=energy_j,
@@ -595,7 +602,9 @@ class VisionEngine:
             energy_tuned_fraction=self.energy.tuned_fraction,
             replicas=self.replicas,
             latency_p99_s=_percentile(lat, 0.99),
-            stage_retraces={s.spec.cu: s.retraces for s in self.stages},
+            # a retrace of the served program retraces every CU's code
+            stage_retraces={s.spec.cu: s.retraces + self.net.retraces
+                            for s in self.stages},
             n_shed=self._n_shed,
             n_deferred=self._n_deferred,
             power_budget_w=self.power_budget_w,

@@ -2,32 +2,38 @@
 
 The paper's host double-buffers CU invocations: while the Body CU crunches
 micro-batch k, the Head CU already streams micro-batch k+1 out of DDR. On
-XLA the same overlap falls out of asynchronous dispatch — every stage call
-returns a future-backed Array immediately — provided the driver *keeps
-multiple micro-batches in flight* instead of blocking batch-by-batch.
+XLA the same overlap falls out of asynchronous dispatch — every program
+call returns a future-backed Array immediately — provided the caller *keeps
+several micro-batches in flight* instead of blocking batch-by-batch.
 
-`PipelinedExecutor.stream` does exactly that: one scheduler tick advances
-every occupied pipeline slot by one stage (walking stages back-to-front so
-a micro-batch moves exactly one stage per tick) and then injects the next
-micro-batch into the Head slot. All dispatches inside a tick are enqueued
-without synchronisation; the only blocking point is harvesting a finished
-Classifier output, by which time the ticks have already queued Head/Body
-work for the following micro-batches.
+One scheduler tick (`advance`) moves every occupied slot one stage on
+(walking stages back-to-front, so a micro-batch moves exactly one stage per
+tick); `inject` then puts the next micro-batch in the first slot. All
+dispatches inside a tick are enqueued without synchronisation; the only
+blocking point is harvesting a finished output. A batch that leaves the
+last stage is returned once `WINDOW` micro-batches are in flight, or at
+once when the tick launched nothing new (the flush at the end of a drain).
+With several stages a finished batch always meets one of the two, so it
+comes back the tick it leaves. With one program (the engine's
+`CompiledNet`) batch k is returned only after batch k+1 is launched: the
+device runs k+1 while the host harvests and records k and forms k+2.
 
-Observability (`tracer=` / `metrics=`, see `repro.obs`): each stage
-dispatch becomes a span on that CU's trace track (dispatch/enqueue time —
-XLA dispatch is asynchronous, so stage *compute* shows up as harvest wait
-at the sync point, which is also traced), plus per-stage dispatch-seconds
-instruments and a harvest-wait histogram; dispatch and harvest spans carry
-the micro-batch's `batch` id when the caller installs `batch_id`. All extra
-clock reads are guarded by `if tracer` / registered-instrument no-ops: with
-observability off the executor performs exactly the clock reads it always
-did (fake-clock tests stay bitwise).
+Observability (`tracer=` / `metrics=`, see `repro.obs`): each program
+dispatch becomes a span `dispatch:<cu>` on that program's trace track
+(dispatch/enqueue time — XLA dispatch is asynchronous, so *compute* shows
+up as harvest wait at the sync point, which is also traced), plus
+per-program dispatch-seconds instruments and a harvest-wait histogram;
+dispatch and harvest spans carry the micro-batch's `batch` id when the
+caller installs `batch_id`. All extra clock reads are guarded by
+`if tracer` / registered-instrument no-ops: with observability off the
+executor performs exactly the clock reads it always did (fake-clock tests
+stay bitwise).
 """
 from __future__ import annotations
 
+import collections
 import time
-from typing import (Any, Callable, Dict, Iterable, Iterator, List,
+from typing import (Any, Callable, Deque, Dict, Iterable, Iterator, List,
                     Optional, Tuple)
 
 import jax
@@ -35,6 +41,11 @@ import jax
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
 from repro.serve.vision.stages import CompiledStage
+
+# micro-batches in flight behind the last program. Fixed: one more than the
+# device can run buys the host/device overlap, and a wider window only adds
+# latency while the host is the slower side.
+WINDOW = 2
 
 
 class PipelinedExecutor:
@@ -45,6 +56,8 @@ class PipelinedExecutor:
         self.stages = stages
         self._slots: List[Optional[Tuple[Any, jax.Array]]] = \
             [None] * len(stages)
+        # outputs of the last stage, dispatched but not yet returned
+        self._done: Deque[Tuple[Any, jax.Array]] = collections.deque()
         # harvest_wait_s reads the same injectable clock as the engine —
         # one time source for every stat (see VisionEngine's clock)
         self._clock = time.perf_counter if clock is None else clock
@@ -79,17 +92,19 @@ class PipelinedExecutor:
     @property
     def busy(self) -> bool:
         """True while any micro-batch is still in flight."""
-        return any(s is not None for s in self._slots)
+        return bool(self._done) or any(s is not None for s in self._slots)
 
     # -- tick-level API (used directly by the multi-model router) ----------
 
     def advance(self) -> Optional[Tuple[Any, jax.Array]]:
         """One scheduler tick: every occupied slot advances exactly one
-        stage (back-to-front, all dispatches async). Frees the Head slot.
-        Returns the (tag, y) that left the last stage this tick, if any —
-        NOT yet blocked on; callers harvest via `harvest`."""
-        finished = None
+        stage (back-to-front, all dispatches async). Frees the first slot.
+        Returns the oldest (tag, y) out of the last stage once `WINDOW`
+        micro-batches are in flight, or at once when nothing new was
+        injected (flush), else None — NOT yet blocked on; callers harvest
+        via `harvest`."""
         self._m_ticks.inc()
+        launched = self._slots[0] is not None
         for i in reversed(range(self.depth)):
             if self._slots[i] is None:
                 continue
@@ -109,19 +124,23 @@ class PipelinedExecutor:
             if i + 1 < self.depth:
                 self._slots[i + 1] = (tag, y)
             else:
-                finished = (tag, y)
-        return finished
+                self._done.append((tag, y))
+        in_flight = len(self._done) + sum(s is not None for s in self._slots)
+        if self._done and (not launched or in_flight >= WINDOW):
+            return self._done.popleft()
+        return None
 
     def inject(self, batch: Tuple[Any, jax.Array]) -> None:
-        """Occupy the Head slot with the next micro-batch."""
+        """Occupy the first slot with the next micro-batch."""
         if self._slots[0] is not None:
-            raise RuntimeError("Head slot occupied — advance() first")
+            raise RuntimeError("first slot occupied — advance() first")
         self._slots[0] = batch
 
     def reset(self) -> None:
         """Drop every in-flight micro-batch (abandoned drain): a later
         stream()/run() must never replay stale tags into its results."""
         self._slots = [None] * self.depth
+        self._done.clear()
 
     def harvest(self, finished: Tuple[Any, jax.Array]) -> Tuple[Any, jax.Array]:
         """Block until a finished output is ready (the only sync point)."""

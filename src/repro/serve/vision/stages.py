@@ -26,12 +26,19 @@ with the monolithic `cu.run_qnet` reference. On accelerators, stage inputs
 are donated at the stage boundary (`donate="auto"`): an intermediate
 activation buffer is dead the moment the next stage consumes it, so XLA can
 reuse it for the stage's own output instead of allocating fresh HBM.
+
+The per-CU programs are the lowering unit (the autotuner and the tests run
+them one by one). The serving engine runs `CompiledNet`: the same CU
+computations chained inside ONE jitted program per batch bucket. On one
+device the stage programs run one after another anyway, and every launch
+costs host time whatever its device work, so one launch per micro-batch
+replaces one per CU.
 """
 from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Callable, List, Optional, Tuple, Union
+from typing import Callable, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +64,7 @@ class StageSpec:
     out_zp: float
     quantizes_input: bool  # Head: float image -> int activations
     dequantizes_output: bool  # Classifier: int logits -> float logits
-    signature: CC.StageSignature
+    signature: Optional[CC.StageSignature]  # None for the whole network
 
 
 class CompiledStage:
@@ -81,6 +88,10 @@ class CompiledStage:
         self._interpret = interpret
         self._tuned = tuned
         self._fused_blocks = fused_blocks
+        self._jit(donate=donate, mesh=mesh)
+
+    def _jit(self, *, donate: bool, mesh) -> None:
+        """Counters, retrace-leak detection, and the jitted program `_fn`."""
         self.mesh = mesh
         self.invocations = 0  # CU invocations dispatched (micro-batches)
         self.traces = 0  # jit cache misses (should stay == #buckets)
@@ -112,17 +123,14 @@ class CompiledStage:
         self._fn = jax.jit(self._named(), **jit_kwargs)
 
     def _named(self) -> Callable[[jax.Array], jax.Array]:
-        """`_trace` as a function named `stage_<cu>` under the scope `<cu>`:
-        each stage's device program is then the XLA module
-        `jit_stage_<cu>`, its ops' metadata sit under `<cu>/`, and kernel
-        names stay those of the kernels."""
-        cu_name = self.spec.cu
+        """`_trace` as a function named `stage_<cu>`: the program is then
+        the XLA module `jit_stage_<cu>`. `_compute` puts each CU's ops
+        under the scope `<cu>/`; kernel names stay those of the kernels."""
 
         def stage(x: jax.Array) -> jax.Array:
-            with jax.named_scope(cu_name):
-                return self._trace(x)
+            return self._trace(x)
 
-        stage.__name__ = stage.__qualname__ = f"stage_{cu_name}"
+        stage.__name__ = stage.__qualname__ = f"stage_{self.spec.cu}"
         return stage
 
     def _trace(self, x: jax.Array) -> jax.Array:
@@ -144,6 +152,10 @@ class CompiledStage:
         return self._body(x)
 
     def _compute(self, x: jax.Array) -> jax.Array:
+        with jax.named_scope(self.spec.cu):
+            return self._run_blocks(x)
+
+    def _run_blocks(self, x: jax.Array) -> jax.Array:
         spec = self.spec
         y = x
         if spec.quantizes_input:
@@ -180,6 +192,42 @@ class CompiledStage:
     def __call__(self, x: jax.Array) -> jax.Array:
         self.invocations += 1
         return self._fn(x)
+
+
+class CompiledNet(CompiledStage):
+    """The whole CU chain as ONE jitted program per batch bucket.
+
+    Runs each stage's computation in order inside `stage_net` (XLA module
+    `jit_stage_net`); every CU keeps its `jax.named_scope`, so op metadata
+    still reads `head/` ... `classifier/`. The (scale, zp) handoff, tuned
+    routes and fused-block choices are the stages' own; activations cross
+    the old stage boundaries inside the program, so there is nothing to
+    donate. Under a mesh one `shard_map` wraps the whole chain. Its spec
+    spans the chain: the input contract of the first stage, the output
+    contract of the last."""
+
+    def __init__(self, stages: Sequence[CompiledStage]):
+        if not stages:
+            raise ValueError("need at least one stage")
+        self.stages = tuple(stages)
+        first, last = self.stages[0].spec, self.stages[-1].spec
+        self.spec = StageSpec(
+            cu="net",
+            blocks=tuple(b for st in self.stages for b in st.spec.blocks),
+            in_scale=first.in_scale,
+            in_zp=first.in_zp,
+            out_scale=last.out_scale,
+            out_zp=last.out_zp,
+            quantizes_input=first.quantizes_input,
+            dequantizes_output=last.dequantizes_output,
+            signature=None,
+        )
+        self._jit(donate=False, mesh=self.stages[0].mesh)
+
+    def _compute(self, x: jax.Array) -> jax.Array:
+        for st in self.stages:
+            x = st._compute(x)
+        return x
 
 
 def _resolve(flag: str, name: str) -> bool:
@@ -308,4 +356,4 @@ def compile_stages(
     return stages
 
 
-__all__ = ["StageSpec", "CompiledStage", "compile_stages"]
+__all__ = ["StageSpec", "CompiledStage", "CompiledNet", "compile_stages"]

@@ -103,16 +103,15 @@ def test_trace_covers_every_request_lifecycle(mnv2_qnet):
     assert all(ev["args"]["status"] == "ok" for ev in ends)
     # queue-wait pairs for every request that rode a micro-batch
     assert len(named("b", "queue_wait")) == len(results)
-    # 4 requests at bucket 2 -> 2 form_batch spans, each stage dispatched
-    # once per micro-batch, one drain span over the whole run()
+    # 4 requests at bucket 2 -> 2 form_batch spans, one dispatch of the
+    # whole-network program per micro-batch, one drain span over the run()
     form = named("X", "form_batch")
     assert len(form) == 2
     assert all(ev["args"]["bucket"] == 2 for ev in form)
-    n_stages = len({ev["name"] for ev in events
-                    if ev["ph"] == "X" and ev["name"].startswith("dispatch:")})
     dispatches = [ev for ev in events
                   if ev["ph"] == "X" and ev["name"].startswith("dispatch:")]
-    assert len(dispatches) == 2 * n_stages and n_stages >= 2
+    assert [ev["name"] for ev in dispatches] == ["dispatch:net"] * 2
+    assert [ev["args"]["batch"] for ev in dispatches] == [0, 1]
     assert len(named("X", "drain")) == 1
     # the summary reconstructs the same lifecycle from the document alone
     summary = summarize_trace(doc)
@@ -129,8 +128,8 @@ def test_trace_covers_every_request_lifecycle(mnv2_qnet):
 
 
 def test_per_batch_spans_share_the_batch_id(mnv2_qnet):
-    """A micro-batch's host life reads form_batch -> place -> dispatch:<cu>
-    ... -> harvest -> record, every span carrying the batch's id; only
+    """A micro-batch's host life reads form_batch -> place -> dispatch:net
+    -> harvest -> record, every span carrying the batch's id; only
     form_batch lists the request ids."""
     doc, _, results = _traced_drain(mnv2_qnet, n=6)
     spans = [ev for ev in doc["traceEvents"] if ev["ph"] == "X"]
@@ -138,15 +137,14 @@ def test_per_batch_spans_share_the_batch_id(mnv2_qnet):
     ids = [ev["args"]["batch"] for ev in form]
     assert ids == [0, 1, 2]
     assert sorted(r for ev in form for r in ev["args"]["rids"]) == sorted(results)
-    n_stages = len({ev["name"] for ev in spans
-                    if ev["name"].startswith("dispatch:")})
     for b in ids:
         mine = [ev for ev in spans if ev.get("args", {}).get("batch") == b]
         names = [ev["name"] for ev in mine]
         assert names.count("form_batch") == 1
         assert names.count("place") == 1 and names.count("record") == 1
         assert names.count("harvest") == 1
-        assert sum(n.startswith("dispatch:") for n in names) == n_stages
+        assert sum(n.startswith("dispatch:") for n in names) == 1
+        assert names.count("dispatch:net") == 1
         order = sorted(mine, key=lambda ev: ev["ts"])
         assert [ev["name"] for ev in order][:2] == ["form_batch", "place"]
         assert [ev["name"] for ev in order][-2:] == ["harvest", "record"]

@@ -156,7 +156,10 @@ def test_stage_trace_count_stays_one_per_bucket(mnv2_qnet):
     for img in _images(4, seed=9):
         eng.submit(img)
     eng.run()
-    assert all(s.traces == 1 for s in eng.stages)  # one bucket -> one trace
+    # one bucket -> one trace of the served program; the per-CU programs
+    # are the lowering unit and serving never traces them
+    assert eng.net.traces == 1
+    assert all(s.traces == 0 for s in eng.stages)
 
 
 def test_prepared_stages_bit_exact_with_reference_stages(mnv2_qnet):

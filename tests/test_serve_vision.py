@@ -5,6 +5,7 @@ padding tails, bounded queue, NaN-safe stats, multi-model routing/fairness,
 sharded multi-replica serving), and a queue-drain throughput smoke test."""
 import math
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +16,10 @@ from repro.core import compiler as CC, cu
 from repro.dist.sharding import data_mesh
 from repro.models import efficientnet as effn, mobilenet_v2 as mnv2
 from repro.models.layers import make_calibrated_qnet
+from repro.obs import MetricsRegistry, Tracer
 from repro.serve.vision import (
     AdmissionError,
+    CompiledNet,
     MultiModelEngine,
     PipelinedExecutor,
     VisionEngine,
@@ -225,6 +228,222 @@ def test_pipeline_stream_abandoned_mid_drain_does_not_leak(mnv2_qnet):
     np.testing.assert_array_equal(
         np.asarray(outs[0]),
         np.asarray(cu.run_qnet(mnv2_qnet, batches[0])))
+
+
+# ---------------------------------------------------------------------------
+# one program per bucket, two micro-batches in flight
+# ---------------------------------------------------------------------------
+
+NET_BUCKETS = (1, 2, 4, 8)
+
+
+@pytest.fixture(scope="module")
+def net_refs(mnv2_qnet, effnet_qnet):
+    """Eight images per model and their `cu.run_qnet` logits (eager, one
+    batch: rows are independent, so any prefix is the reference for the
+    same prefix of images)."""
+    imgs = _images(8, seed=3)
+    return {name: (imgs, np.asarray(cu.run_qnet(q, jnp.asarray(imgs))))
+            for name, q in (("mnv2_qnet", mnv2_qnet),
+                            ("effnet_qnet", effnet_qnet))}
+
+
+@pytest.fixture(scope="module")
+def net_engines(mnv2_qnet, effnet_qnet):
+    return {"mnv2_qnet": VisionEngine(mnv2_qnet, buckets=NET_BUCKETS),
+            "effnet_qnet": VisionEngine(effnet_qnet, buckets=NET_BUCKETS)}
+
+
+@pytest.mark.parametrize("bucket", NET_BUCKETS)
+@pytest.mark.parametrize("qnet_fixture", ["mnv2_qnet", "effnet_qnet"])
+def test_one_program_bit_exact_at_every_bucket(qnet_fixture, bucket,
+                                               net_engines, net_refs):
+    """`bucket` images form one unpadded micro-batch of exactly that
+    bucket; the whole-network program's logits equal `cu.run_qnet`'s."""
+    eng = net_engines[qnet_fixture]
+    imgs, ref = net_refs[qnet_fixture]
+    rids = [eng.submit(img) for img in imgs[:bucket]]
+    results = eng.run()
+    assert eng.stats().pad_fraction == 0.0
+    got = np.stack([results[r].logits for r in rids])
+    np.testing.assert_array_equal(got, ref[:bucket])
+
+
+def test_net_program_chains_the_stage_contracts(mnv2_qnet):
+    """The served program spans the CU chain: the first stage's input
+    contract, the last stage's output contract, every block once."""
+    stages = compile_stages(mnv2_qnet)
+    net = CompiledNet(stages)
+    assert net.spec.cu == "net" and net.stages == tuple(stages)
+    assert (net.spec.in_scale, net.spec.in_zp) == (
+        stages[0].spec.in_scale, stages[0].spec.in_zp)
+    assert (net.spec.out_scale, net.spec.out_zp) == (
+        stages[-1].spec.out_scale, stages[-1].spec.out_zp)
+    assert net.spec.quantizes_input and net.spec.dequantizes_output
+    assert net.spec.blocks == mnv2_qnet.spec.blocks
+    with pytest.raises(ValueError, match="at least one stage"):
+        CompiledNet([])
+
+
+def _traced_engine(qnet, clock, **kw):
+    tracer = Tracer(clock, origin_s=0.0)
+    reg = MetricsRegistry()
+    eng = VisionEngine(qnet, buckets=(2,), clock=clock, tracer=tracer,
+                       metrics=reg, name="m", **kw)
+    return eng, tracer, reg
+
+
+def test_one_dispatch_span_per_micro_batch(mnv2_qnet):
+    """A traced drain of 3 micro-batches launches the whole network once
+    per batch (`dispatch:net`), and each CU still counts one invocation
+    per micro-batch."""
+    eng, tracer, _ = _traced_engine(mnv2_qnet, FakeClock(step=1e-3))
+    for img in _images(6):
+        eng.submit(img)
+    eng.run()
+    spans = [ev for ev in tracer.to_chrome()["traceEvents"]
+             if ev["ph"] == "X" and ev["name"].startswith("dispatch:")]
+    assert [ev["name"] for ev in spans] == ["dispatch:net"] * 3
+    assert [ev["args"]["batch"] for ev in spans] == [0, 1, 2]
+    assert [ev["args"]["rows"] for ev in spans] == [2, 2, 2]
+    stats = eng.stats()
+    assert stats.stage_invocations == {
+        st.spec.cu: 3 for st in eng.stages}
+    assert set(stats.stage_invocations) == {
+        CC.HEAD, CC.BODY, CC.TAIL, CC.CLASSIFIER}
+
+
+def test_one_program_retrace_leak_warns(mnv2_qnet):
+    """A non-bucket shape straight into the served program warns, bumps
+    its counter, and counts against every CU (all of their code is
+    retraced); bucketed shapes stay silent."""
+    eng, _, reg = _traced_engine(mnv2_qnet, FakeClock(step=1e-3))
+    with pytest.warns(RuntimeWarning, match="retrace at non-bucketed"):
+        eng.net(jnp.asarray(_images(3), jnp.float32))  # 3 is not a bucket
+    assert eng.stats().stage_retraces == {st.spec.cu: 1 for st in eng.stages}
+    key = 'serve_stage_retraces_total{cu="net",model="m"}'
+    assert reg.snapshot()["counters"][key] == 1
+    eng.net(jnp.asarray(_images(2), jnp.float32))
+    assert eng.net.retraces == 1
+
+
+def test_harvest_waits_for_the_next_launch(mnv2_qnet):
+    """Two micro-batches in flight: batch k's harvest starts after batch
+    k+1's `dispatch:net` span (the device has the next batch queued while
+    the host records this one); only the last batch of the drain is
+    harvested without a launch behind it. Results come back in
+    submission order, bit-exact."""
+    eng, tracer, _ = _traced_engine(mnv2_qnet, FakeClock(step=1e-3))
+    imgs = _images(8)
+    rids = [eng.submit(img) for img in imgs]
+    results = eng.run()
+    spans = [ev for ev in tracer.to_chrome()["traceEvents"]
+             if ev["ph"] == "X"]
+
+    def starts(name):
+        return {ev["args"]["batch"]: ev["ts"] for ev in spans
+                if ev["name"] == name}
+
+    dispatch, harvest, record = (starts(n) for n in
+                                 ("dispatch:net", "harvest", "record"))
+    assert sorted(dispatch) == sorted(harvest) == [0, 1, 2, 3]
+    for k in range(3):
+        assert harvest[k] > dispatch[k + 1]
+    assert harvest[3] > dispatch[3]
+    assert [b for b, _ in sorted(record.items(), key=lambda kv: kv[1])] \
+        == [0, 1, 2, 3]
+    got = np.stack([results[r].logits for r in rids])
+    np.testing.assert_array_equal(
+        got, np.asarray(cu.run_qnet(mnv2_qnet, jnp.asarray(imgs))))
+
+
+class _Program:
+    """A stand-in stage program: records launches, returns its input."""
+
+    def __init__(self, cu_name, log):
+        self.spec = types.SimpleNamespace(cu=cu_name)
+        self._log = log
+
+    def __call__(self, x):
+        self._log.append((self.spec.cu, x))
+        return x
+
+
+def _drive(pipe, n):
+    """Tick the executor the way the router does (advance, inject, then
+    harvest); returns (tick, tag) per returned batch."""
+    out, tick, pending = [], 0, list(range(n))
+    while pending or pipe.busy:
+        finished = pipe.advance()
+        if pending:
+            pipe.inject((pending[0], pending.pop(0)))
+        if finished is not None:
+            out.append((tick, pipe.harvest(finished)[0]))
+        tick += 1
+    return out
+
+
+def test_window_returns_a_batch_after_the_next_launch():
+    """One program: `advance` returns batch k only once batch k+1 is
+    launched, and at once when nothing new was injected (the flush)."""
+    log = []
+    pipe = PipelinedExecutor([_Program("net", log)])
+    pipe.inject(("a", 0))
+    assert pipe.advance() is None and pipe.busy  # a launched, alone
+    pipe.inject(("b", 1))
+    assert pipe.advance()[0] == "a"  # b launched behind it
+    assert [x for _, x in log] == [0, 1]
+    assert pipe.advance()[0] == "b"  # flush: nothing new injected
+    assert pipe.advance() is None and not pipe.busy
+    assert _drive(pipe, 4) == [(2, 0), (3, 1), (4, 2), (5, 3)]
+
+
+@pytest.mark.parametrize("depth", [2, 3, 4])
+def test_staged_executor_returns_a_batch_the_tick_it_finishes(depth):
+    """Several stages: a batch comes back on the tick its last stage is
+    launched (tick depth + k for batch k), as before the window."""
+    log = []
+    pipe = PipelinedExecutor([_Program(f"s{i}", log) for i in range(depth)])
+    assert _drive(pipe, 5) == [(depth + k, k) for k in range(5)]
+    assert len(log) == 5 * depth and not pipe.busy
+
+
+def test_one_program_stream_abandoned_leaves_nothing_in_flight(mnv2_qnet):
+    """Breaking out of stream() with two batches in flight drops both: a
+    later drain returns exactly its own batches."""
+    pipe = PipelinedExecutor([CompiledNet(compile_stages(mnv2_qnet))])
+    batches = [jnp.asarray(_images(2, seed=i)) for i in range(4)]
+    for _ in pipe.stream(enumerate(batches)):
+        break  # batch 1 still in flight, batch 2 injected
+    assert not pipe.busy
+    outs = pipe.run(batches[:3])
+    assert len(outs) == 3
+    for x, y in zip(batches, outs):
+        np.testing.assert_array_equal(
+            np.asarray(y), np.asarray(cu.run_qnet(mnv2_qnet, x)))
+
+
+def test_router_drain_over_two_engines_returns_every_result(
+        mnv2_qnet, effnet_qnet, net_refs):
+    """Several micro-batches per model through the router's tick loop,
+    each model with two in flight: every request comes back ok and
+    bit-exact, and both pipelines end empty."""
+    clock = FakeClock(step=1e-4)
+    mm = MultiModelEngine({
+        "mnv2_qnet": VisionEngine(mnv2_qnet, buckets=(2,), clock=clock),
+        "effnet_qnet": VisionEngine(effnet_qnet, buckets=(2,), clock=clock),
+    }, clock=clock)
+    n = {"mnv2_qnet": 6, "effnet_qnet": 4}  # 3 and 2 micro-batches
+    handles = [(mm.submit(m, img, now=0.0), i)
+               for m, k in n.items() for i, img in enumerate(net_refs[m][0][:k])]
+    results = mm.run()
+    assert set(results) == {h for h, _ in handles}
+    for h, i in handles:
+        assert results[h].status == "ok"
+        np.testing.assert_array_equal(results[h].logits, net_refs[h[0]][1][i])
+    assert not any(e.pipe.busy for e in mm.engines.values())
+    assert sorted(m for m, _ in mm.dispatch_log) == \
+        ["effnet_qnet"] * 2 + ["mnv2_qnet"] * 3
 
 
 # ---------------------------------------------------------------------------

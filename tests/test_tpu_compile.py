@@ -4,8 +4,9 @@ Interpret mode (every other kernel test) cannot see what the TPU compiler
 refuses: block shapes off the (8, 128) tiling, value slices Mosaic cannot
 lay out, operand types the MXU does not take, fast memory a kernel
 overruns. These tests compile the three CU kernels with `interpret=False`,
-one stage program of each CU role, and the Tail stage sharded over the
-4-replica 'data' mesh, for a described (not attached) v5e. Nothing runs;
+one stage program of each CU role, the whole-network program the engine
+serves, and the Tail stage sharded over the 4-replica 'data' mesh, for a
+described (not attached) v5e. Nothing runs;
 a compile that passes is not a chip run.
 
 The topology is described inside a module fixture, never at import: only
@@ -144,6 +145,24 @@ def test_stage_program_compiles(one_chip, mnv2_stages, role):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_net_program_compiles(one_chip, mnv2_stages):
+    """The served program: the four stages chained in one `stage_net`.
+    Each CU keeps its kernels, under its own scope in the op metadata:
+    the stem's irb0 (depthwise + pointwise), 16 fused IRBs, one
+    pointwise Tail and one Classifier matmul."""
+    from repro.serve.vision.stages import CompiledNet
+
+    _, stages = mnv2_stages
+    x = jax.ShapeDtypeStruct((BATCH, 224, 224, 3), jnp.float32,
+                             sharding=one_chip)
+    text = CompiledNet(stages)._fn.lower(x).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert {st.spec.cu: sum(f"/{st.spec.cu}/" in ln for ln in calls)
+            for st in stages} == {"head": 2, "body": 16, "tail": 1,
+                                  "classifier": 1}
+    assert len(calls) == 20
+
+
 def _chain(stages, x):
     for st in stages:
         x = st._trace(x)
@@ -169,4 +188,22 @@ def test_data_mesh_stage_compiles(topo, mnv2_qnet):
                              sharding=batch_sharding(mesh))
     text = stages[tail]._fn.lower(x).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
+def test_data_mesh_net_compiles(topo, mnv2_qnet):
+    """The --replicas 4 path of the served program: one `shard_map`
+    around the whole chain, each replica on its own rows, no collective."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.dist.sharding import batch_sharding
+    from repro.serve.vision.stages import CompiledNet
+
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    net = CompiledNet(_pallas_stages(mnv2_qnet, mesh))
+    x = jax.ShapeDtypeStruct((BATCH, 224, 224, 3), jnp.float32,
+                             sharding=batch_sharding(mesh))
+    text = net._fn.lower(x).compile().as_text()
+    assert text.count("tpu_custom_call") > 0
     assert "all-gather" not in text and "all-reduce" not in text
