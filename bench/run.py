@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Run one benchmark cell once, on the chip this process finds.
+"""Run one benchmark cell once, on the chips this process finds.
 
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
@@ -13,6 +13,8 @@ Set-up (timed as `setup_s`, process start to the first timed request):
 weights and integer tables from the configuration's weight seed
 (`deploy.py`, one device program), the program's serving engine over them,
 every stage program of the cell's buckets warmed, one drain per bucket.
+A cell on several chips serves over a 'data' mesh of exactly those chips:
+each micro-batch is split into one shard of rows per chip.
 `--seed` draws the images, the arrival phases and jitter, and the sample of
 answers checked; the weights stay those of the configuration, so every run
 serves the same compiled programs (the program compiles its weights into
@@ -142,6 +144,7 @@ class LayerInputs:
     traced_batches: List[int]  # rows (padding included) per micro-batch in it
     traced_images: int  # images answered by drains inside it
     spans: List[Dict]  # the program's span tracer, Chrome events of the window
+    chips: int  # devices the cell ran on (its data mesh's replicas)
 
 
 class _Profiler:
@@ -225,11 +228,27 @@ def check_answers(dep, pool, win) -> Dict[str, Dict]:
             "mismatched_logits": {"value": mismatched, "limit": 0}}
 
 
+def engine_mesh(devices, buckets):
+    """None on one chip; else a 'data' mesh over exactly the cell's devices.
+    Every bucket has to split evenly: the engine would round it up, and the
+    profiler counts rows by the traffic's buckets."""
+    if len(devices) == 1:
+        return None
+    from repro.dist.sharding import data_mesh
+
+    if any(b % len(devices) for b in buckets):
+        raise SystemExit(f"bench: buckets {list(buckets)} are not all multiples "
+                         f"of the cell's {len(devices)} chips")
+    return data_mesh(devices=devices)
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
              t_start: float, keep_trace: Optional[str] = None) -> Dict:
     from repro.obs import Tracer
     from repro.serve.vision import MultiModelEngine, VisionEngine
 
+    buckets = sorted(cell.traffic["buckets"])
+    mesh = engine_mesh(devices, buckets)
     fam = netlib.family(cell.cfg)
     blocks = fam.blocks(cell.cfg)
     net = fam.program_netspec(cell.cfg)
@@ -245,9 +264,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     pool = rng.uniform(-1, 1, (cell.traffic["pool"], *netlib.input_shape(cell.cfg))
                        ).astype(np.float32)
     tracer = Tracer() if trace else None
-    buckets = sorted(cell.traffic["buckets"])
     # no deadline and no queue bound: a late frame is slow, never refused
-    eng = VisionEngine(deploy.to_program_qnet(dep, net), buckets=buckets,
+    eng = VisionEngine(deploy.to_program_qnet(dep, net), buckets=buckets, mesh=mesh,
                        tracer=tracer, name=cell.model, max_queue=sys.maxsize)
     router = MultiModelEngine({cell.model: eng})
     phases["engine"] = time.perf_counter()
@@ -291,6 +309,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         peak_mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                        for d in devices)
         red = prof.reduced() if trace else None
+    if red is not None and red.device_count != len(devices):
+        raise SystemExit(f"bench: the trace holds {red.device_count} device planes, "
+                         f"the cell ran on {len(devices)} chips")
     del router, eng
 
     setup_s = win.start - t_start
@@ -328,7 +349,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             blocks=blocks, input_hw=cell.cfg["input_hw"],
             peak=counts.load_peak(d.device_kind), macs_per_image=macs, trace=red,
             traced_batches=prof.batches, traced_images=prof.images,
-            spans=tracer.to_chrome()["traceEvents"])
+            spans=tracer.to_chrome()["traceEvents"], chips=len(devices))
         metrics = {}
         for m in cell.per_layer:
             v = reader(m["name"])(inputs)

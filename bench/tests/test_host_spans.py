@@ -198,7 +198,7 @@ def fixture_inputs():
             blocks=net.family(cfg).blocks(cfg), input_hw=cfg["input_hw"],
             peak=counts.load_peak("TPU v5 lite"), macs_per_image=cfg["macs_per_image"],
             trace=T.reduce(profile, notes=notes), traced_batches=[8] * 16,
-            traced_images=128, spans=[])
+            traced_images=128, spans=[], chips=1)
     return profile, inputs
 
 

@@ -70,3 +70,22 @@ def test_reduce_with_given_window_and_notes(profile):
     assert red.window_s == pytest.approx((half[1] - half[0]) * 1e-9)
     assert all(label == "outside bench annotations" for label, _ in red.idle_gaps)
     assert T.reduce(profile, notes=[]) is None  # no window to reduce over
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_mfu_divides_by_the_cells_chips(profile, chips):
+    import counts
+    import net
+    import run
+
+    red = T.reduce(profile)
+    cfg = net.load_config(net.BENCH / "configs" / "efficientnet_compact-128-w4.json")
+    peak = counts.load_peak("TPU v5 lite")
+    images = 2 * 64  # the fixture's two drains
+    inputs = run.LayerInputs(
+        blocks=[], input_hw=cfg["input_hw"], peak=peak,
+        macs_per_image=cfg["macs_per_image"], trace=red, traced_batches=[8] * 16,
+        traced_images=images, spans=[], chips=chips)
+    one_chip = 100 * 2 * cfg["macs_per_image"] * images / (
+        red.window_s * peak["int8_ops_per_s"])  # the one-chip formula
+    assert run.reader("mfu")(inputs) == one_chip / chips
